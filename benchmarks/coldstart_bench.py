@@ -5,9 +5,9 @@ its (pre-built) bitstream loads, while a fresh JAX process re-traces and
 re-compiles everything. This bench measures what the persistence layer
 (``repro.serve.persist`` + the persistent compilation cache) buys:
 
-- **cold**: a worker process facing an empty cache dir — builds the model,
-  AOT-compiles the bucket ladder, serves. This is what every replica paid
-  before PR 10.
+- **cold**: a worker process facing an empty artifact dir and an empty
+  compilation cache — builds the model, AOT-compiles the bucket ladder,
+  serves. This is what every replica paid before PR 10.
 - **warm**: the same worker facing the artifacts the cold run left behind —
   restores the checkpointed registry (params + ``jax.export`` plan blobs),
   warms execute-only against the shared compilation cache, serves.
@@ -19,26 +19,38 @@ persistence layer controls; the spawn-measured wall time is recorded in
 each row's derived metrics as ``spawn_to_first_s``.
 
     # CI shape: two invocations, one shared dir, then the paired-row gate
-    python -m benchmarks.coldstart_bench --quick --phase cold --cache-dir D --json coldstart.json
-    python -m benchmarks.coldstart_bench --quick --phase warm --cache-dir D --json coldstart.json
+    python -m benchmarks.coldstart_bench --quick --phase cold --json coldstart.json
+    python -m benchmarks.coldstart_bench --quick --phase warm --json coldstart.json
     python scripts/check_bench_regression.py coldstart.json coldstart.json --coldstart-min-speedup 5
 
-The cold phase leaves warm artifacts behind (checkpoint + cache entries,
-including one discarded populate run so the restored-plan programs are
-cached, not just the AOT ones), which is exactly the fleet deployment
-story: the first replica ever pays cold, all later replicas pay warm.
+The artifact dir is fixed (``--cache-dir``, default ``.coldstart-cache`` in
+the checkout) and the compilation cache is the one every entry point uses
+(``repro.core.compile_cache``). The cold phase empties both first; it
+refuses to empty a non-empty cache that ``JAX_COMPILATION_CACHE_DIR``
+names, since that directory is not this bench's to delete. The cold phase
+leaves warm artifacts behind (checkpoint + cache entries, including one
+discarded populate run so the restored-plan programs are cached, not just
+the AOT ones), which is exactly the fleet deployment story: the first
+replica ever pays cold, all later replicas pay warm.
+
+Each phase spawns worker processes, so it runs from its own command line
+and never from a parent that has already touched jax.
 """
 from __future__ import annotations
 
 import argparse
 import json
 import os
+import shutil
 import subprocess
 import sys
-import tempfile
 import time
 
+from repro.core import compile_cache
+
 MANIFEST = "registry.json"  # mirror of repro.serve.persist.MANIFEST
+DEFAULT_DIR = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), ".coldstart-cache")
 
 
 def _worker(cache_dir: str, *, requests: int, quick: bool,
@@ -55,8 +67,7 @@ def _worker(cache_dir: str, *, requests: int, quick: bool,
         cmd.append("--save")
     if trace:
         cmd += ["--trace", trace]
-    env = dict(os.environ,
-               REPRO_COMPILE_CACHE=os.path.join(cache_dir, "xla"))
+    env = dict(os.environ)
     if spawn_t0:
         env["REPRO_FLEET_T0"] = repr(time.time())
     proc = subprocess.run(cmd, env=env, text=True, capture_output=True)
@@ -79,16 +90,25 @@ def _row(result: dict, extra: str = "") -> tuple[float, str]:
     return serve_s * 1e6, derived
 
 
+def _empty_for_cold(cache_dir: str) -> None:
+    """Empty the artifact dir and the compilation cache (see module doc)."""
+    xla_dir = compile_cache.resolve_dir()
+    if (os.environ.get(compile_cache.ENV_DIR) and os.path.isdir(xla_dir)
+            and os.listdir(xla_dir)):
+        raise SystemExit(
+            f"--phase cold needs an empty compilation cache, but "
+            f"{compile_cache.ENV_DIR}={xla_dir} holds entries — empty it or "
+            "unset the variable (cold numbers from a warm cache would be a "
+            "lie)")
+    for d in (cache_dir, xla_dir):
+        shutil.rmtree(d, ignore_errors=True)
+    os.makedirs(cache_dir)
+
+
 def run_cold(cache_dir: str, *, requests: int, quick: bool,
              trace: str = "") -> tuple[float, str]:
     """Measure the cold phase, then leave warm artifacts behind."""
-    marker = os.path.join(cache_dir, "registry", MANIFEST)
-    if os.path.exists(marker):
-        raise SystemExit(
-            f"--phase cold needs a fresh dir, but {marker} exists — point "
-            "--cache-dir somewhere empty (cold numbers from a warm dir "
-            "would be a lie)")
-    os.makedirs(cache_dir, exist_ok=True)
+    _empty_for_cold(cache_dir)
     cold = _worker(cache_dir, requests=requests, quick=quick,
                    build=True, save=True, trace=trace)
     # populate pass (discarded): the restored-plan programs differ from the
@@ -120,25 +140,6 @@ def run_warm(cache_dir: str, *, requests: int, quick: bool,
 
 
 # ---------------------------------------------------------------------------
-# benchmarks.run integration (one function: both phases, fresh temp dir)
-# ---------------------------------------------------------------------------
-
-def coldstart_cold_vs_warm_bench():
-    """Cold and warm start-to-first-response rows (subprocess-measured)."""
-    from .common import emit
-
-    with tempfile.TemporaryDirectory(prefix="coldstart_") as d:
-        cold_us, cold_derived = run_cold(d, requests=8, quick=True)
-        emit("coldstart/first_response_cold", cold_us, cold_derived)
-        warm_us, warm_derived = run_warm(d, requests=8, quick=True,
-                                         cold_us=cold_us)
-        emit("coldstart/first_response_warm", warm_us, warm_derived)
-
-
-ALL = [coldstart_cold_vs_warm_bench]
-
-
-# ---------------------------------------------------------------------------
 # Standalone CLI (the CI coldstart job: cold and warm as separate invocations)
 # ---------------------------------------------------------------------------
 
@@ -165,9 +166,8 @@ def main(argv=None) -> int:
         description="cold vs warm start-to-first-response bench")
     ap.add_argument("--phase", choices=("cold", "warm", "both"),
                     default="both")
-    ap.add_argument("--cache-dir", default="",
-                    help="shared artifact dir (required for cold/warm "
-                         "phases; a temp dir when omitted with --phase both)")
+    ap.add_argument("--cache-dir", default=DEFAULT_DIR,
+                    help="shared artifact dir; the cold phase empties it")
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--json", default="", metavar="OUT",
@@ -178,38 +178,25 @@ def main(argv=None) -> int:
                     help="obs trace of the measured worker (CI artifact)")
     args = ap.parse_args(argv)
 
-    if not args.cache_dir and args.phase != "both":
-        ap.error(f"--phase {args.phase} requires --cache-dir (cold and "
-                 "warm must share it)")
-
     rows = {}
-    tmp = None
-    cache_dir = args.cache_dir
-    if not cache_dir:
-        tmp = tempfile.TemporaryDirectory(prefix="coldstart_")
-        cache_dir = tmp.name
-    try:
-        if args.phase in ("cold", "both"):
-            us, derived = run_cold(cache_dir, requests=args.requests,
-                                   quick=args.quick, trace=args.trace)
-            rows["coldstart/first_response_cold"] = (us, derived)
-            print(f"coldstart/first_response_cold,{us:.1f},{derived}")
-        if args.phase in ("warm", "both"):
-            cold_us = rows.get("coldstart/first_response_cold",
-                               (None, ""))[0]
-            if cold_us is None and args.json and os.path.exists(args.json):
-                with open(args.json) as f:
-                    prior = json.load(f).get("rows", {})
-                cold_us = prior.get("coldstart/first_response_cold",
-                                    {}).get("us_per_call")
-            us, derived = run_warm(cache_dir, requests=args.requests,
-                                   quick=args.quick, trace=args.trace,
-                                   cold_us=cold_us)
-            rows["coldstart/first_response_warm"] = (us, derived)
-            print(f"coldstart/first_response_warm,{us:.1f},{derived}")
-    finally:
-        if tmp is not None:
-            tmp.cleanup()
+    cache_dir = os.path.abspath(args.cache_dir)
+    if args.phase in ("cold", "both"):
+        us, derived = run_cold(cache_dir, requests=args.requests,
+                               quick=args.quick, trace=args.trace)
+        rows["coldstart/first_response_cold"] = (us, derived)
+        print(f"coldstart/first_response_cold,{us:.1f},{derived}")
+    if args.phase in ("warm", "both"):
+        cold_us = rows.get("coldstart/first_response_cold", (None, ""))[0]
+        if cold_us is None and args.json and os.path.exists(args.json):
+            with open(args.json) as f:
+                prior = json.load(f).get("rows", {})
+            cold_us = prior.get("coldstart/first_response_cold",
+                                {}).get("us_per_call")
+        us, derived = run_warm(cache_dir, requests=args.requests,
+                               quick=args.quick, trace=args.trace,
+                               cold_us=cold_us)
+        rows["coldstart/first_response_warm"] = (us, derived)
+        print(f"coldstart/first_response_warm,{us:.1f},{derived}")
 
     if args.json:
         _merge_snapshot(args.json, rows)

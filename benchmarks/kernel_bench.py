@@ -193,7 +193,7 @@ def snn_engine_queue_bench():
         "fused": lambda: ops.fused_spike_accum(
             occ, w, depth=depth, H=hw, W=hw, invalid=fmt.invalid_word, **kw),
         # interpret=True pinned explicitly: this row IS the interpreter
-        # baseline, regardless of platform or REPRO_PALLAS_COMPILE
+        # baseline, whatever the platform
         "interp": lambda: raw_event_accum(q.words[0], q.counts[0], w, vm,
                                           interpret=True, **kw),
     }, rounds=4)

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import platform
 import sys
 import time
@@ -69,7 +70,9 @@ def _run_dryrun(multi_pod: bool) -> None:
 
     A subprocess because launch/dryrun.py must set
     ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before jax
-    initializes — doing that in-process would poison every other suite.
+    initializes — doing that in-process would poison every other suite. It
+    runs before this process touches jax, and on the CPU backend only, so
+    it never contends for an accelerator.
     """
     import subprocess
 
@@ -77,7 +80,7 @@ def _run_dryrun(multi_pod: bool) -> None:
     if multi_pod:
         cmd.append("--multi-pod")
     print(f"# --with-dryrun: {' '.join(cmd)}", file=sys.stderr)
-    subprocess.run(cmd, check=True)
+    subprocess.run(cmd, check=True, env=dict(os.environ, JAX_PLATFORMS="cpu"))
 
 
 def main() -> None:
@@ -98,14 +101,15 @@ def main() -> None:
     if args.with_dryrun:
         _run_dryrun(args.multi_pod)
 
-    from . import break_even, coldstart_bench, distributions, kernel_bench, \
-        memory_study, paper_tables, parallel_bench, roofline_report, \
-        serve_bench
+    # benchmarks.coldstart_bench is not a suite here: it spawns worker
+    # processes that need the accelerator, which this process holds once a
+    # suite has run — run it from its own command line
+    from . import break_even, distributions, kernel_bench, memory_study, \
+        paper_tables, parallel_bench, roofline_report, serve_bench
 
     suites = (paper_tables.ALL + distributions.ALL + memory_study.ALL +
               kernel_bench.ALL + break_even.ALL + serve_bench.ALL +
-              parallel_bench.ALL + coldstart_bench.ALL +
-              roofline_report.ALL)
+              parallel_bench.ALL + roofline_report.ALL)
 
     print("name,us_per_call,derived")
     failures = 0

@@ -72,7 +72,7 @@ def eqn_anchor(eqn, root: str) -> tuple[str, int]:
     try:
         from jax._src import source_info_util
 
-        frame = source_info_util.user_frame(eqn.source_info)
+        frame = source_info_util.user_frame(eqn.source_info.traceback)
         if frame is not None:
             f = frame.file_name
             if os.path.isabs(f) and f.startswith(root.rstrip(os.sep) + os.sep):

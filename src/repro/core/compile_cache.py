@@ -7,13 +7,18 @@ ships a content-addressed persistent cache (keyed on the HLO module, the
 compile options, and the jaxlib version); this module is the one place the
 repo turns it on:
 
-- :func:`configure` points ``jax_compilation_cache_dir`` at a directory and
-  drops the size/time floors so *every* executable persists (the paper nets
-  are small; the default floors would skip them all).
-- ``REPRO_COMPILE_CACHE=<dir>`` does the same with no code change —
-  ``core.engine`` calls :func:`configure_from_env` at import, so any entry
-  point (pytest, benches, the serve fleet) inherits the cache by exporting
-  one env var. Unset, nothing changes.
+- :func:`configure` turns the cache on in the directory JAX's own
+  ``JAX_COMPILATION_CACHE_DIR`` names, and only where that is unset in one
+  fixed, git-ignored directory of the checkout (:data:`DEFAULT_DIR`). It
+  drops the size/time floors so *every* executable persists (the paper
+  nets are small; the default floors would skip them all). No code chooses
+  any other directory: the path is part of what the cache is looked up by,
+  so a directory that moves never hits.
+- ``core.engine`` calls :func:`configure_from_env` at import, so any entry
+  point (pytest, benches, the serve fleet) that runs with
+  ``JAX_COMPILATION_CACHE_DIR`` exported gets the floors and counters too.
+  Entry points that always want the cache (the fleet's workers, the
+  cold-start bench, ``chip_smoke.py``) call :func:`configure` themselves.
 - Hit/miss/put counters: JAX does not expose cache statistics, so
   :func:`configure` wraps the internal get/put hooks
   (``jax._src.compilation_cache``) and bumps both the module-level
@@ -38,8 +43,13 @@ import os
 
 from .. import obs
 
-ENV_DIR = "REPRO_COMPILE_CACHE"
+ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
 ENV_STATS = "REPRO_CACHE_STATS"
+
+#: where the cache lives when ``JAX_COMPILATION_CACHE_DIR`` is unset
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
 
 #: process-wide cache statistics, live-updated once :func:`configure` ran;
 #: ``instrumented`` records whether the private-hook wrap succeeded.
@@ -53,21 +63,23 @@ def cache_dir() -> str | None:
     return _state["dir"]
 
 
-def configure(directory: str | None = None) -> str | None:
-    """Enable the persistent compilation cache under ``directory``.
+def resolve_dir() -> str:
+    """The directory :func:`configure` uses (does not import jax)."""
+    return os.environ.get(ENV_DIR) or DEFAULT_DIR
 
-    ``directory=None`` falls back to ``$REPRO_COMPILE_CACHE``; with neither
-    set this is a no-op returning None. Idempotent — repeat calls just
-    repoint the directory. Returns the active directory.
+
+def configure() -> str:
+    """Enable the persistent compilation cache; returns its directory.
+
+    ``$JAX_COMPILATION_CACHE_DIR`` when set (JAX already reads it, so it is
+    left alone), else :data:`DEFAULT_DIR`. Idempotent.
     """
-    directory = directory or os.environ.get(ENV_DIR) or None
-    if directory is None:
-        return None
     import jax
 
-    directory = os.path.abspath(directory)
+    directory = resolve_dir()
     os.makedirs(directory, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", directory)
+    if not os.environ.get(ENV_DIR):
+        jax.config.update("jax_compilation_cache_dir", directory)
     # persist everything: the paper nets compile in well under the default
     # 1s floor, and the default min-entry-size would skip them silently
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
@@ -81,7 +93,7 @@ def configure(directory: str | None = None) -> str | None:
 
 
 def configure_from_env() -> str | None:
-    """:func:`configure` iff ``$REPRO_COMPILE_CACHE`` is set (else no-op)."""
+    """:func:`configure` iff ``$JAX_COMPILATION_CACHE_DIR`` is set."""
     if os.environ.get(ENV_DIR):
         return configure()
     return None

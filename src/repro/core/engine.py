@@ -56,12 +56,13 @@ from .aeq import (AEQ, aeq_from_raster, phase_occupancy, segment_keep,
 from .encoding import AEFormat, encode_ttfs
 from .neuron import (NeuronModel, _on_registry_change, get_neuron_model,
                      surrogate_model)
-from .snn_layers import dense_conv_hwc, event_conv2d, spike_maxpool_hwc
+from .snn_layers import (PRECISION, dense_conv_hwc, event_conv2d,
+                         spike_maxpool_hwc)
 
 # Persistent compilation cache (docs/SERVING.md "Cold start"): every entry
-# point imports the engine, so this is the chokepoint that makes
-# REPRO_COMPILE_CACHE=<dir> enough to carry jit and AOT compiles across
-# process death. No env var, no behaviour change.
+# point imports the engine, so with JAX_COMPILATION_CACHE_DIR exported this
+# is where the repo's floors and hit/miss counters join JAX's own cache.
+# No env var, no behaviour change.
 compile_cache.configure_from_env()
 
 # Engine-internal raster layout: (T, H, W, C) — channels-last end to end, so
@@ -311,8 +312,8 @@ def _valid_offsets_map(hw: int, K: int) -> jnp.ndarray:
     ones = jnp.ones((1, 1, hw, hw))
     kern = jnp.ones((K, K, 1, 1))
     return jax.lax.conv_general_dilated(
-        ones, kern, (1, 1), "SAME", dimension_numbers=("NCHW", "HWIO", "NHWC")
-    )[0, :, :, 0]
+        ones, kern, (1, 1), "SAME", dimension_numbers=("NCHW", "HWIO", "NHWC"),
+        precision=PRECISION)[0, :, :, 0]
 
 
 def _segment_occupancy(fmt: AEFormat, raster: jnp.ndarray) -> jnp.ndarray:
@@ -465,7 +466,8 @@ class DenseBackend:
                 # all T steps in one batched conv (T is the batch axis)
                 cur = jax.lax.conv_general_dilated(
                     raster.astype(w.dtype), w, (1, 1), "SAME",
-                    dimension_numbers=("NHWC", "HWIO", "NHWC")) + b
+                    dimension_numbers=("NHWC", "HWIO", "NHWC"),
+                    precision=PRECISION) + b
             else:
                 c1 = dense_conv_hwc(analog, w) + b
                 cur = jnp.broadcast_to(c1, (T,) + c1.shape)
@@ -615,7 +617,8 @@ class QueueBackend:
                            * cp.kernel * cp.kernel, jnp.int32)
             c1 = jax.lax.conv_general_dilated(
                 analog.astype(w.dtype), w, (1, 1), "SAME",
-                dimension_numbers=("NHWC", "HWIO", "NHWC")) + b
+                dimension_numbers=("NHWC", "HWIO", "NHWC"),
+                precision=PRECISION) + b
             cur = jnp.broadcast_to(c1[:, None], (B, T) + c1.shape[1:])
 
         step = jax.vmap(_conv_step(cp, model, vth))
@@ -699,7 +702,8 @@ def _sparse_analog_fn(cp: ConvPlan, cfg: SNNConfig):
         B = analog.shape[0]
         c1 = jax.lax.conv_general_dilated(
             analog.astype(w.dtype), w, (1, 1), "SAME",
-            dimension_numbers=("NHWC", "HWIO", "NHWC")) + b
+            dimension_numbers=("NHWC", "HWIO", "NHWC"),
+            precision=PRECISION) + b
         cur = jnp.broadcast_to(c1[:, None], (B, cfg.T) + c1.shape[1:])
         step = jax.vmap(_conv_step(cp, model, vth))
         carry = _init_carry_batch(cp, cfg, vth, w.dtype, B)
@@ -833,7 +837,7 @@ def _output_layer(params_out, T: int, raster: jnp.ndarray,
     if weight_bits is not None and T <= 127:
         logits = _quant_head(flat.sum(0)[None], w, weight_bits)[0] + b * T
     else:
-        logits = (flat @ w).sum(0) + b * T
+        logits = jnp.matmul(flat, w, precision=PRECISION).sum(0) + b * T
     ev = (flat > 0).sum().astype(jnp.int32)
     row = LayerStats(ev, _zero(), ev * jnp.int32(w.shape[1]), _zero(), _zero())
     return logits, row
@@ -919,7 +923,7 @@ def _output_layer_batch(params_out, T: int, raster: jnp.ndarray,
     if weight_bits is not None and T <= 127:
         logits = _quant_head(flat.sum(1), w, weight_bits) + b * T
     else:
-        logits = (flat @ w).sum(1) + b * T
+        logits = jnp.matmul(flat, w, precision=PRECISION).sum(1) + b * T
     ev = (flat > 0).sum(axis=(1, 2)).astype(jnp.int32)
     z = jnp.zeros((B,), jnp.int32)
     row = LayerStats(ev, z, ev * jnp.int32(w.shape[1]), z, z)
@@ -997,7 +1001,8 @@ def _execute_diff(plan: LayerPlan, model: NeuronModel, cfg: SNNConfig,
         if raster is not None:
             cur = jax.lax.conv_general_dilated(
                 raster.astype(w.dtype), w, (1, 1), "SAME",
-                dimension_numbers=("NHWC", "HWIO", "NHWC")) + b
+                dimension_numbers=("NHWC", "HWIO", "NHWC"),
+                precision=PRECISION) + b
         else:
             c1 = dense_conv_hwc(analog, w) + b
             cur = jnp.broadcast_to(c1, (cfg.T,) + c1.shape)

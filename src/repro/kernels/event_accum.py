@@ -89,10 +89,8 @@ def _kernel(words_ref, counts_ref, w_ref, vm_in_ref, vm_ref, *, K, n_win, bits, 
                 ok = live & (ty >= 0) & (ty < H) & (tx >= 0) & (tx < W)
                 tyc = jnp.clip(ty, 0, H - 1)
                 txc = jnp.clip(tx, 0, W - 1)
-                cur = pl.load(vm_ref, (tyc, txc, slice(None)))
                 wv = w_ref[dy, dx, :]
-                new = cur + jnp.where(ok, wv, jnp.zeros_like(wv))
-                pl.store(vm_ref, (tyc, txc, slice(None)), new)
+                vm_ref[tyc, txc, :] += jnp.where(ok, wv, jnp.zeros_like(wv))
 
 
 @functools.partial(jax.jit, static_argnames=("K", "n_win", "bits", "interpret"))

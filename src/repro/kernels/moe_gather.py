@@ -24,9 +24,8 @@ def _kernel(idx_ref, x_ref, o_ref, *, block_rows):
     for r in range(block_rows):  # static unroll within the block
         tok = idx_ref[r0 + r]
         ok = tok >= 0
-        row = pl.load(x_ref, (pl.dslice(jnp.maximum(tok, 0), 1), slice(None)))
-        pl.store(o_ref, (pl.dslice(r, 1), slice(None)),
-                 jnp.where(ok, row, jnp.zeros_like(row)))
+        row = x_ref[pl.ds(jnp.maximum(tok, 0), 1), :]
+        o_ref[pl.ds(r, 1), :] = jnp.where(ok, row, jnp.zeros_like(row))
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))

@@ -1,14 +1,15 @@
 """Public jit'd entry points for the Pallas kernels.
 
-On CPU (this container) the kernels execute with interpret=True — the kernel
-*body* runs, validating the logic; on a real TPU set ``REPRO_PALLAS_COMPILE=1``
-(or pass interpret=False) to compile them. ``backend='ref'`` selects the
-pure-jnp oracle (used for differential testing and as the XLA fallback).
+The platform decides how a Pallas kernel runs: compiled by Mosaic on a
+TPU, under the Pallas interpreter everywhere else (the kernel *body* runs,
+validating the logic). ``backend='ref'`` selects the pure-jnp oracle used
+for differential testing. On a TPU neither the interpreter nor an oracle
+may stand in for a kernel: :func:`fused_spike_accum` and
+:func:`quant_matmul` raise rather than run one there.
 """
 from __future__ import annotations
 
 import collections
-import os
 
 import jax
 import jax.numpy as jnp
@@ -33,21 +34,29 @@ dispatch_counts: collections.Counter = collections.Counter()
 
 
 def _interpret() -> bool:
-    if os.environ.get("REPRO_PALLAS_COMPILE", "0") == "1":
-        return False
     return jax.default_backend() != "tpu"
+
+
+# realizations that only validate a kernel: never run in place of one on TPU
+_OFF_CHIP_ONLY = ("ref", "pallas_interpret", "sparse_pallas_interpret")
+
+
+def _refuse_on_tpu(kernel: str, impl: str) -> None:
+    if impl in _OFF_CHIP_ONLY and jax.default_backend() == "tpu":
+        raise RuntimeError(
+            f"{kernel}: realization {impl!r} is an oracle or the Pallas "
+            "interpreter and does not run on a TPU; use the compiled kernel "
+            "(the default)")
 
 
 def default_spike_impl() -> str:
     """Default implementation of the fused spike pipeline — never interpret.
 
     'pallas' (compiled Mosaic) on TPU; 'xla' (the fused-conv realization of
-    the same semantics) everywhere else — keyed off the actual jax backend,
-    not REPRO_PALLAS_COMPILE, so a host that *meant* to compile for TPU but
-    fell back to CPU still runs (compiled) rather than crashing in Mosaic
-    lowering. The Pallas *interpreter* is only reachable by explicit
-    request (``impl='pallas_interpret'``) — it is a logic-validation tool,
-    not an execution path.
+    the same semantics) everywhere else — keyed off the actual jax backend.
+    The Pallas *interpreter* is only reachable by explicit request
+    (``impl='pallas_interpret'``) off the chip — it is a logic-validation
+    tool, not an execution path.
     """
     return "pallas" if jax.default_backend() == "tpu" else "xla"
 
@@ -77,6 +86,7 @@ def fused_spike_accum(occ, weights, *, K, n_win, bits, depth, H, W,
     (also honored by 'ref', which then anchors the quant parity tests).
     """
     impl = impl or default_spike_impl()
+    _refuse_on_tpu("fused_spike_accum", impl)
     dispatch_counts[f"fused:{impl}"] += 1
     obs.counter(f"kernels.dispatch.fused:{impl}")
     if impl == "ref":
@@ -143,6 +153,7 @@ def default_quant_impl() -> str:
 
 def quant_matmul(a_q, b_q, a_scale, b_scale, *, backend=None, **blocks):
     backend = backend or default_quant_impl()
+    _refuse_on_tpu("quant_matmul", backend)
     dispatch_counts[f"quant_matmul:{backend}"] += 1
     obs.counter(f"kernels.dispatch.quant_matmul:{backend}")
     if backend == "ref":

@@ -26,12 +26,9 @@ def _kernel(occ_ref, words_ref, count_ref, *, n_win, bits, depth, invalid):
         wx = p % n_win
         word = (wy << bits) | wx
         slot = jnp.minimum(cnt, depth - 1)  # clamp; overflow tracked by count
-        cur = pl.load(words_ref, (pl.ds(slot, 1),))
-        pl.store(
-            words_ref,
-            (pl.ds(slot, 1),),
-            jnp.where(fired & (cnt < depth), jnp.full((1,), word, jnp.int32), cur),
-        )
+        cur = words_ref[pl.ds(slot, 1)]
+        words_ref[pl.ds(slot, 1)] = jnp.where(
+            fired & (cnt < depth), jnp.full((1,), word, jnp.int32), cur)
         return cnt + fired.astype(jnp.int32)
 
     total = jax.lax.fori_loop(0, P, body, jnp.int32(0))

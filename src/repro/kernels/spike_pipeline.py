@@ -7,7 +7,7 @@ words) and ``event_accum`` (queue words -> membrane charge). DeepFire2
 wins from *not* doing that — the event stream stays on-chip between the
 encoder and the accumulator. This module is that fusion:
 
-    per-phase occupancy ──compact──▶ AE words in VMEM ──accumulate──▶ charge
+    per-phase occupancy ──compact──▶ AE words in SMEM ──accumulate──▶ charge
                           (never leaves the chip)
 
 Design points (vs. the unfused kernels):
@@ -17,7 +17,7 @@ Design points (vs. the unfused kernels):
   row. Queue backends previously reached batch > 1 only through an outer
   ``jax.vmap`` of the whole single-sample program.
 - **Double-buffered fixed-depth segments.** The queue depth ``D`` is split
-  into segments of ``seg`` words held in a ``(2, K², seg)`` VMEM scratch:
+  into segments of ``seg`` words held in a ``2·K²·seg``-word SMEM scratch:
   while segment ``s`` drains into the membrane map, segment ``s+1`` is
   compacted into the other buffer — the paper's Fig. 3 segmented AEQ as a
   software pipeline. The packed words never materialize in HBM.
@@ -53,12 +53,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ..audit.contracts import KernelContract
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU scratch spaces; absent on some CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover - environment without pallas-tpu
-    pltpu = None
+from ..audit.contracts import KernelContract
+from ..core.snn_layers import PRECISION
 
 # Declared resource/dtype intent, verified by ``python -m repro.audit``
 # (see docs/CONTRACTS.md): fp32 accumulate (no quant path here), no host
@@ -67,22 +65,17 @@ CONTRACT = KernelContract(name="fused_spike_accum_pallas",
                           module=__name__, accum_dtype="float32")
 
 
-def vmem_blocks(*, K, n_win, depth, H, W, C_out, seg=None, **_unused):
+def vmem_blocks(*, K, H, W, C_out, **_unused):
     """Per-grid-cell resident buffers as data, for ``audit.vmem``.
 
-    Mirrors :func:`fused_spike_accum_pallas`'s BlockSpecs and scratch
-    exactly — ``(name, block shape, bytes per element, double-buffered)``;
-    pipelined in/out blocks are double-buffered by the Mosaic emitter,
-    scratch is not.
+    Mirrors :func:`fused_spike_accum_pallas`'s VMEM BlockSpecs exactly —
+    ``(name, block shape, bytes per element, double-buffered)``; pipelined
+    in/out blocks are double-buffered by the Mosaic emitter. The occupancy
+    block and the queue segments live in SMEM (:func:`kernel_specs`).
     """
-    K2 = K * K
-    P = n_win * n_win
-    seg = _default_seg(depth, n_win) if seg is None else min(seg, depth)
     return [
-        ("occ_block", (K2, P), 4, True),
         ("w_block", (K, K, C_out), 4, True),
         ("out_block", (H, W, C_out), 4, True),
-        ("seg_scratch", (2, K2, seg), 4, False),
     ]
 
 
@@ -94,84 +87,94 @@ def _default_seg(depth: int, n_win: int) -> int:
 # ---------------------------------------------------------------------------
 # Pallas TPU kernel
 # ---------------------------------------------------------------------------
+#
+# Mosaic layout of one grid cell:
+#
+# - the (K2, P) occupancy block sits in SMEM, flattened to (1, K2*P) so its
+#   two minor dimensions are full: the encoder reads it one scalar at a time
+#   (a vector walk over positions would be a load at a dynamic lane offset);
+# - the AE queue segments are SMEM scratch, written and read as scalars;
+# - the (K, K, C_out) weight slice and the (H, W, C_out) membrane map are
+#   VMEM blocks whose two minor dimensions are full, and each event updates
+#   one C_out-wide row of the map.
 
-def _kernel(occ_ref, w_ref, cur_ref, buf_ref, *,
-            K, n_win, bits, depth, seg, H, W, invalid):
-    """One grid step: all events of one (sample*step, channel) queue set."""
+def _pipeline(occ_ref, w_ref, cur_ref, buf_ref, *, n_pos, n_seg, K, n_win,
+              bits, depth, seg, H, W, invalid):
+    """Compact + accumulate one (sample*step, channel) cell.
+
+    ``n_pos`` bounds the encoder's position walk and ``n_seg(totals)`` the
+    segment loop; both may be traced scalars (the gated kernel in
+    ``spike_sparse`` passes occupancy-derived bounds).
+    """
     K2 = K * K
     P = n_win * n_win
     pad = K // 2
     mask = (1 << bits) - 1
-    n_seg = -(-min(depth, P) // seg)  # ceil; slots beyond P never fill
 
-    @pl.when(pl.program_id(1) == 0)
-    def _init():
-        cur_ref[...] = jnp.zeros_like(cur_ref)
-
-    occ = occ_ref[...]                                    # (K2, P)
-    fired_all = occ > 0
-    # per-phase totals, capped at depth: the drain-side liveness bound
-    # (identical to AEQ.counts for this segment set)
-    totals = jnp.minimum(fired_all.sum(axis=1), depth)    # (K2,)
+    def slot(bs, ph, i):
+        return (bs * K2 + ph) * seg + i
 
     def fill(s, bs):
         """Compact events with queue slot in [s*seg, (s+1)*seg) into buf[bs].
 
         Sequential append with a running per-phase count — the hardware
         queue write pointer, exactly as in kernels/spike_compact.py, but
-        the destination is VMEM scratch instead of an HBM output.
+        the destination is on-chip scratch instead of an HBM output.
+        Returns the per-phase totals capped at ``depth`` (identical to
+        AEQ.counts for this segment set): the drain-side liveness bound.
         """
         base = s * seg
-        pl.store(buf_ref, (pl.ds(bs, 1), slice(None), slice(None)),
-                 jnp.full((1, K2, seg), invalid, jnp.int32))
+
+        def clear(i, _):
+            for ph in range(K2):
+                buf_ref[slot(bs, ph, i)] = jnp.int32(invalid)
+            return 0
+
+        jax.lax.fori_loop(0, seg, clear, 0)
 
         def body(p, cnt):
-            col = pl.load(occ_ref, (slice(None), pl.ds(p, 1)))[:, 0]
-            fired = col > 0
-            wy = p // n_win
-            wx = p % n_win
-            word = (wy << bits) | wx
+            word = ((p // n_win) << bits) | (p % n_win)
+            out = []
             for ph in range(K2):  # static unroll: the K2 interlaced queues
+                fired = occ_ref[0, ph * P + p] > 0
                 sl = cnt[ph] - base
 
-                @pl.when(fired[ph] & (sl >= 0) & (sl < seg)
-                         & (cnt[ph] < depth))
+                @pl.when(fired & (sl >= 0) & (sl < seg) & (cnt[ph] < depth))
                 def _append():
-                    pl.store(
-                        buf_ref,
-                        (pl.ds(bs, 1), pl.ds(ph, 1),
-                         pl.ds(jnp.clip(sl, 0, seg - 1), 1)),
-                        jnp.full((1, 1, 1), word, jnp.int32))
-            return cnt + fired.astype(jnp.int32)
+                    buf_ref[slot(bs, ph, sl)] = word
 
-        jax.lax.fori_loop(0, P, body, jnp.zeros((K2,), jnp.int32))
+                out.append(cnt[ph] + fired.astype(jnp.int32))
+            return tuple(out)
 
-    def drain(s, bs):
+        cnt = jax.lax.fori_loop(0, n_pos, body,
+                                tuple(jnp.int32(0) for _ in range(K2)))
+        return tuple(jnp.minimum(c, depth) for c in cnt)
+
+    def drain(s, bs, totals):
         """Accumulate segment ``s`` (resident in buf[bs]) into the charge map."""
         base = s * seg
 
         def dbody(d, _):
             for ph in range(K2):  # static unroll: one event per phase, no
                 ky, kx = ph // K, ph % K  # write conflicts (interlacing)
-                word = pl.load(
-                    buf_ref, (pl.ds(bs, 1), pl.ds(ph, 1), pl.ds(d, 1))
-                )[0, 0, 0]
+                word = buf_ref[slot(bs, ph, d)]
                 i_c = (word >> bits) & mask
                 j_c = word & mask
-                live = (base + d < totals[ph]) & (i_c < n_win)
                 y = i_c * K + ky
                 x = j_c * K + kx
-                for dy in range(K):  # static unroll: kernel offsets
-                    for dx in range(K):
-                        ty = y - dy + pad
-                        tx = x - dx + pad
-                        ok = live & (ty >= 0) & (ty < H) & (tx >= 0) & (tx < W)
-                        tyc = jnp.clip(ty, 0, H - 1)
-                        txc = jnp.clip(tx, 0, W - 1)
-                        cur = pl.load(cur_ref, (tyc, txc, slice(None)))
-                        wv = w_ref[dy, dx, :]
-                        pl.store(cur_ref, (tyc, txc, slice(None)),
-                                 cur + jnp.where(ok, wv, jnp.zeros_like(wv)))
+
+                @pl.when((base + d < totals[ph]) & (i_c < n_win))
+                def _event():
+                    for dy in range(K):  # static unroll: kernel offsets
+                        for dx in range(K):
+                            ty = y - dy + pad
+                            tx = x - dx + pad
+
+                            @pl.when((ty >= 0) & (ty < H)
+                                     & (tx >= 0) & (tx < W))
+                            def _add():
+                                cur_ref[ty, pl.ds(tx, 1), :] += (
+                                    w_ref[dy, pl.ds(dx, 1), :])
             return 0
 
         jax.lax.fori_loop(0, seg, dbody, 0)
@@ -179,19 +182,59 @@ def _kernel(occ_ref, w_ref, cur_ref, buf_ref, *,
     # software pipeline over double-buffered segments: compact s+1 while s
     # drains (on hardware the fill is the encoder writing ahead of the
     # accumulator; sequentialized here, the structure is what lowers)
-    fill(0, 0)
+    totals = fill(0, 0)
 
     def sbody(s, _):
         bs = jax.lax.rem(s, 2)
 
-        @pl.when(s + 1 < n_seg)
+        @pl.when(s + 1 < n_seg(totals))
         def _prefetch():
             fill(s + 1, jax.lax.rem(s + 1, 2))
 
-        drain(s, bs)
+        drain(s, bs, totals)
         return 0
 
-    jax.lax.fori_loop(0, n_seg, sbody, 0)
+    jax.lax.fori_loop(0, n_seg(totals), sbody, 0)
+
+
+def _kernel(occ_ref, w_ref, cur_ref, buf_ref, *,
+            K, n_win, bits, depth, seg, H, W, invalid):
+    """One grid step: all events of one (sample*step, channel) queue set."""
+    P = n_win * n_win
+    n_seg = -(-min(depth, P) // seg)  # ceil; slots beyond P never fill
+
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        cur_ref[...] = jnp.zeros_like(cur_ref)
+
+    _pipeline(occ_ref, w_ref, cur_ref, buf_ref, n_pos=P,
+              n_seg=lambda totals: n_seg, K=K, n_win=n_win, bits=bits,
+              depth=depth, seg=seg, H=H, W=W, invalid=invalid)
+
+
+def kernel_specs(N, C_in, K, P, H, W, C_out, seg):
+    """BlockSpecs and scratch shared by both Pallas realizations.
+
+    The wrapper passes the occupancy as (N, C_in, 1, K2*P) and the weights
+    as (C_in, K, K, C_out) so that every block's two minor dimensions are
+    the array's own.
+    """
+    K2 = K * K
+    in_specs = [
+        pl.BlockSpec((None, None, 1, K2 * P), lambda n, c: (n, c, 0, 0),
+                     memory_space=pltpu.SMEM),
+        pl.BlockSpec((None, K, K, C_out), lambda n, c: (c, 0, 0, 0)),
+    ]
+    out_spec = pl.BlockSpec((None, H, W, C_out), lambda n, c: (n, 0, 0, 0))
+    scratch = [pltpu.SMEM((2 * K2 * seg,), jnp.int32)]
+    return in_specs, out_spec, scratch
+
+
+def kernel_operands(occ, weights):
+    """(N, C_in, K2, P) occupancy and HWIO weights in the kernels' layout."""
+    N, C_in, K2, P = occ.shape
+    return (occ.reshape(N, C_in, 1, K2 * P),
+            jnp.transpose(weights, (2, 0, 1, 3)))
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -214,24 +257,18 @@ def fused_spike_accum_pallas(
     N, C_in, K2, P = occ.shape
     C_out = weights.shape[-1]
     seg = _default_seg(depth, n_win) if seg is None else min(seg, depth)
-
-    if pltpu is None:  # pragma: no cover - pallas-tpu unavailable
-        raise RuntimeError("pallas TPU support unavailable")
-    scratch = [pltpu.VMEM((2, K2, seg), jnp.int32)]
-
+    in_specs, out_spec, scratch = kernel_specs(N, C_in, K, P, H, W, C_out,
+                                               seg)
     return pl.pallas_call(
         functools.partial(_kernel, K=K, n_win=n_win, bits=bits, depth=depth,
                           seg=seg, H=H, W=W, invalid=invalid),
         grid=(N, C_in),
-        in_specs=[
-            pl.BlockSpec((None, None, K2, P), lambda n, c: (n, c, 0, 0)),
-            pl.BlockSpec((K, K, None, C_out), lambda n, c: (0, 0, c, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, H, W, C_out), lambda n, c: (n, 0, 0, 0)),
+        in_specs=in_specs,
+        out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((N, H, W, C_out), weights.dtype),
         scratch_shapes=scratch,
         interpret=interpret,
-    )(occ, weights)
+    )(*kernel_operands(occ, weights))
 
 
 # ---------------------------------------------------------------------------
@@ -269,4 +306,4 @@ def fused_spike_accum_xla(
     m = m[:, :H, :W, :]
     return jax.lax.conv_general_dilated(
         m, weights, (1, 1), "SAME",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))
+        dimension_numbers=("NHWC", "HWIO", "NHWC"), precision=PRECISION)

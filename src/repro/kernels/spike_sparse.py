@@ -43,14 +43,12 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..audit.contracts import KernelContract, QuantContract
 from ..core.quantization import quantize_symmetric
-
-try:  # TPU scratch spaces; absent on some CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover - environment without pallas-tpu
-    pltpu = None
+from .spike_pipeline import (_default_seg, _pipeline, kernel_operands,
+                             kernel_specs)
 
 # Declared resource/dtype intent, verified by ``python -m repro.audit``
 # (see docs/CONTRACTS.md): with ``weight_bits`` the accumulate is int8
@@ -62,22 +60,15 @@ CONTRACT = KernelContract(name="fused_spike_accum_sparse",
                           allowed_host_syncs=("occupancy-gate",))
 
 
-def vmem_blocks(*, K, n_win, depth, H, W, C_out, seg=None, **_unused):
-    """Per-grid-cell resident buffers of the gated Pallas kernel, as data.
+def vmem_blocks(*, K, H, W, C_out, **_unused):
+    """Per-grid-cell VMEM buffers of the gated Pallas kernel, as data.
 
-    The dense-walk pipeline's blocks plus the two (1,)-scalar gate inputs
-    (cell total + fill bound); see ``audit.vmem``.
+    The dense-walk pipeline's blocks; the gate pair (cell total + fill
+    bound) sits in SMEM beside the occupancy block. See ``audit.vmem``.
     """
-    K2 = K * K
-    P = n_win * n_win
-    seg = _default_seg(depth, n_win) if seg is None else min(seg, depth)
     return [
-        ("occ_block", (K2, P), 4, True),
         ("w_block", (K, K, C_out), 4, True),
-        ("tot_gate", (1,), 4, True),
-        ("pmax_gate", (1,), 4, True),
         ("out_block", (H, W, C_out), 4, True),
-        ("seg_scratch", (2, K2, seg), 4, False),
     ]
 
 
@@ -212,116 +203,34 @@ def fused_spike_accum_sparse(
 # Occupancy-gated Pallas kernel (per-cell early exit + ragged row dispatch)
 # ---------------------------------------------------------------------------
 
-def _sparse_kernel(occ_ref, w_ref, tot_ref, pmax_ref, cur_ref, buf_ref, *,
+def _sparse_kernel(occ_ref, w_ref, gate_ref, cur_ref, buf_ref, *,
                    K, n_win, bits, depth, seg, H, W, invalid):
     """``spike_pipeline._kernel`` with occupancy gates.
 
-    Differences from the dense-walk kernel:
+    ``gate_ref`` is this cell's SMEM pair (total events, 1 + last active
+    position). Differences from the dense-walk kernel:
 
     - the whole fill/drain pipeline sits under ``pl.when(cell_total > 0)``,
       so an empty ``(row, channel)`` grid cell costs only the accumulator
       init;
-    - the fill loop walks positions ``[0, pmax)`` (the prefetched 1 + last
-      active position) instead of all P;
+    - the fill loop walks positions ``[0, pmax)`` instead of all P;
     - the segment loop walks only the segments the deepest phase queue
       actually fills (a traced ``fori_loop`` bound), instead of the static
       worst case ``ceil(min(depth, P) / seg)``.
     """
-    K2 = K * K
-    P = n_win * n_win
-    pad = K // 2
-    mask = (1 << bits) - 1
-
     @pl.when(pl.program_id(1) == 0)
     def _init():
         cur_ref[...] = jnp.zeros_like(cur_ref)
 
-    cell_total = tot_ref[0]
+    def n_seg(totals):  # segments the fullest queue actually reaches
+        deepest = functools.reduce(jnp.maximum, totals)
+        return jax.lax.div(deepest + seg - 1, seg)
 
-    @pl.when(cell_total > 0)
+    @pl.when(gate_ref[0, 0] > 0)
     def _work():
-        occ = occ_ref[...]                                 # (K2, P)
-        fired_all = occ > 0
-        totals = jnp.minimum(fired_all.sum(axis=1), depth)  # (K2,)
-        pmax = pmax_ref[0]
-        # segments the fullest queue actually reaches (traced bound)
-        n_seg = jax.lax.div(jnp.max(totals) + seg - 1, seg)
-
-        def fill(s, bs):
-            base = s * seg
-            pl.store(buf_ref, (pl.ds(bs, 1), slice(None), slice(None)),
-                     jnp.full((1, K2, seg), invalid, jnp.int32))
-
-            def body(p, cnt):
-                col = pl.load(occ_ref, (slice(None), pl.ds(p, 1)))[:, 0]
-                fired = col > 0
-                wy = p // n_win
-                wx = p % n_win
-                word = (wy << bits) | wx
-                for ph in range(K2):
-                    sl = cnt[ph] - base
-
-                    @pl.when(fired[ph] & (sl >= 0) & (sl < seg)
-                             & (cnt[ph] < depth))
-                    def _append():
-                        pl.store(
-                            buf_ref,
-                            (pl.ds(bs, 1), pl.ds(ph, 1),
-                             pl.ds(jnp.clip(sl, 0, seg - 1), 1)),
-                            jnp.full((1, 1, 1), word, jnp.int32))
-                return cnt + fired.astype(jnp.int32)
-
-            # only positions [0, pmax) can hold events in this cell
-            jax.lax.fori_loop(0, pmax, body, jnp.zeros((K2,), jnp.int32))
-
-        def drain(s, bs):
-            base = s * seg
-
-            def dbody(d, _):
-                for ph in range(K2):
-                    ky, kx = ph // K, ph % K
-                    word = pl.load(
-                        buf_ref, (pl.ds(bs, 1), pl.ds(ph, 1), pl.ds(d, 1))
-                    )[0, 0, 0]
-                    i_c = (word >> bits) & mask
-                    j_c = word & mask
-                    live = (base + d < totals[ph]) & (i_c < n_win)
-                    y = i_c * K + ky
-                    x = j_c * K + kx
-                    for dy in range(K):
-                        for dx in range(K):
-                            ty = y - dy + pad
-                            tx = x - dx + pad
-                            ok = (live & (ty >= 0) & (ty < H)
-                                  & (tx >= 0) & (tx < W))
-                            tyc = jnp.clip(ty, 0, H - 1)
-                            txc = jnp.clip(tx, 0, W - 1)
-                            cur = pl.load(cur_ref, (tyc, txc, slice(None)))
-                            wv = w_ref[dy, dx, :]
-                            pl.store(
-                                cur_ref, (tyc, txc, slice(None)),
-                                cur + jnp.where(ok, wv, jnp.zeros_like(wv)))
-                return 0
-
-            jax.lax.fori_loop(0, seg, dbody, 0)
-
-        fill(0, 0)
-
-        def sbody(s, _):
-            bs = jax.lax.rem(s, 2)
-
-            @pl.when(s + 1 < n_seg)
-            def _prefetch():
-                fill(s + 1, jax.lax.rem(s + 1, 2))
-
-            drain(s, bs)
-            return 0
-
-        jax.lax.fori_loop(0, n_seg, sbody, 0)
-
-
-def _default_seg(depth: int, n_win: int) -> int:
-    return max(1, min(64, depth, n_win * n_win))
+        _pipeline(occ_ref, w_ref, cur_ref, buf_ref, n_pos=gate_ref[0, 1],
+                  n_seg=n_seg, K=K, n_win=n_win, bits=bits, depth=depth,
+                  seg=seg, H=H, W=W, invalid=invalid)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -362,9 +271,6 @@ def fused_spike_accum_sparse_pallas(
     C_out = weights.shape[-1]
     seg = _default_seg(depth, n_win) if seg is None else min(seg, depth)
 
-    if pltpu is None and not interpret:  # pragma: no cover
-        raise RuntimeError("pallas TPU support unavailable")
-
     w_scale = None
     if weight_bits is not None:
         w_q, w_scale = quantize_symmetric(weights, weight_bits)
@@ -387,32 +293,30 @@ def fused_spike_accum_sparse_pallas(
         N_run = N
 
     # per-(row, channel) gate scalars: total events and 1 + last active
-    # position (the fill-loop bound)
+    # position (the fill-loop bound), one SMEM pair per grid cell
     fired_any = occ > 0
     cell_tot = fired_any.sum((-1, -2)).astype(jnp.int32)   # (N_run, C_in)
     p_idx = jnp.arange(P, dtype=jnp.int32)
     cell_pmax = jnp.max(
         jnp.where(fired_any.any(-2), p_idx[None, None] + 1, 0), -1
     ).astype(jnp.int32)                                    # (N_run, C_in)
+    gates = jnp.stack([cell_tot, cell_pmax], -1)[:, :, None]
 
-    scratch = ([pltpu.VMEM((2, K2, seg), jnp.int32)] if pltpu is not None
-               else [jax.ShapeDtypeStruct((2, K2, seg), jnp.int32)])
-
+    in_specs, out_spec, scratch = kernel_specs(N_run, C_in, K, P, H, W,
+                                               C_out, seg)
+    in_specs.append(pl.BlockSpec((None, None, 1, 2),
+                                 lambda n, c: (n, c, 0, 0),
+                                 memory_space=pltpu.SMEM))
     out = pl.pallas_call(
         functools.partial(_sparse_kernel, K=K, n_win=n_win, bits=bits,
                           depth=depth, seg=seg, H=H, W=W, invalid=invalid),
         grid=(N_run, C_in),
-        in_specs=[
-            pl.BlockSpec((None, None, K2, P), lambda n, c: (n, c, 0, 0)),
-            pl.BlockSpec((K, K, None, C_out), lambda n, c: (0, 0, c, 0)),
-            pl.BlockSpec((None, None, 1), lambda n, c: (n, c, 0)),
-            pl.BlockSpec((None, None, 1), lambda n, c: (n, c, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, H, W, C_out), lambda n, c: (n, 0, 0, 0)),
+        in_specs=in_specs,
+        out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((N_run, H, W, C_out), weights.dtype),
         scratch_shapes=scratch,
         interpret=interpret,
-    )(occ, weights, cell_tot[..., None], cell_pmax[..., None])
+    )(*kernel_operands(occ, weights), gates)
 
     if row_order is not None:
         # scatter the active-row results back into the full (N, …) output;

@@ -30,7 +30,6 @@ import contextlib
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .. import obs
@@ -85,11 +84,11 @@ def batch_runner_sharded(cfg, backend_name: str, mesh: Mesh):
 
         run = jax.vmap(run_one, in_axes=(None, None, 0))
 
-    # check_rep=False: outputs are all batch-sharded (nothing claims
+    # check_vma=False: outputs are all batch-sharded (nothing claims
     # replication), and several engine primitives lack replication rules
-    sharded = shard_map(run, mesh=mesh,
-                        in_specs=(P(), P(), P(DATA_AXIS)),
-                        out_specs=P(DATA_AXIS), check_rep=False)
+    sharded = jax.shard_map(run, mesh=mesh,
+                            in_specs=(P(), P(), P(DATA_AXIS)),
+                            out_specs=P(DATA_AXIS), check_vma=False)
     fn = jax.jit(sharded)
     _RUNNERS[key] = fn
     return fn

@@ -26,18 +26,28 @@ load-servable inference service:
 See ``docs/SERVING.md`` for architecture, policies, and the cold-start
 path.
 """
-from .api import InferRequest, InferResponse, ServeError  # noqa: F401
-from .batching import DEFAULT_BUCKETS, BucketPolicy  # noqa: F401
-from .persist import (CheckpointError, CorruptCheckpointError,  # noqa: F401
-                      StaleCheckpointError, load_registry, save_registry)
-from .registry import ModelHandle, ModelRegistry  # noqa: F401
-from .runtime import ServeRuntime  # noqa: F401
+import importlib as _importlib
 
-__all__ = [
-    "InferRequest", "InferResponse", "ServeError",
-    "BucketPolicy", "DEFAULT_BUCKETS",
-    "ModelHandle", "ModelRegistry",
-    "ServeRuntime",
-    "CheckpointError", "StaleCheckpointError", "CorruptCheckpointError",
-    "save_registry", "load_registry",
-]
+# name -> submodule, resolved on first access: importing ``repro.serve``
+# must not import jax, so the fleet parent (``python -m repro.serve.fleet``)
+# can stay off the accelerator while its replica processes use it
+_EXPORTS = {
+    "InferRequest": "api", "InferResponse": "api", "ServeError": "api",
+    "BucketPolicy": "batching", "DEFAULT_BUCKETS": "batching",
+    "ModelHandle": "registry", "ModelRegistry": "registry",
+    "ServeRuntime": "runtime",
+    "CheckpointError": "persist", "StaleCheckpointError": "persist",
+    "CorruptCheckpointError": "persist", "save_registry": "persist",
+    "load_registry": "persist",
+}
+
+__all__ = list(_EXPORTS)
+
+
+def __getattr__(name):
+    try:
+        module = _EXPORTS[name]
+    except KeyError:
+        raise AttributeError(
+            f"module {__name__!r} has no attribute {name!r}") from None
+    return getattr(_importlib.import_module(f".{module}", __name__), name)

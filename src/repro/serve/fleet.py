@@ -7,10 +7,20 @@ from the shared artifacts —
 
     python -m repro.serve.fleet --replicas 4 --cache-dir /var/repro
 
-``--cache-dir D`` holds everything shared: ``D/registry`` (the params +
-plan checkpoint), ``D/xla`` (the persistent compilation cache; exported to
-workers as ``REPRO_COMPILE_CACHE``), and ``D/study`` (train/convert
-artifacts when ``--trained``). Workers are plain subprocesses of this
+``--cache-dir D`` holds the shared artifacts: ``D/registry`` (the params +
+plan checkpoint) and ``D/study`` (train/convert artifacts when
+``--trained``). Every process keeps its compiles in the one persistent
+compilation cache (``core/compile_cache.py``: ``JAX_COMPILATION_CACHE_DIR``
+or the checkout's fixed default).
+
+**One process per chip.** An accelerator belongs to the one process that
+first touches it, so the parent never imports jax: a prepare child builds
+the checkpoint (if missing), reports the platform, and exits before any
+replica starts. Replicas do not pin a device, so each would claim every
+chip of a TPU host; the parent therefore refuses to start more than one
+replica process on a TPU (pinning one replica per chip is not built yet).
+
+Workers are plain subprocesses of this
 module with ``--worker``; each restores the registry, warms the bucket
 ladder (execute-only after a plan restore), serves the same deterministic
 request set, and reports one JSON line: time-to-first-response measured
@@ -34,26 +44,21 @@ import subprocess
 import sys
 import time
 
-import numpy as np
-
 from .. import obs
 from ..core import compile_cache
-from .api import ServeError
-from .batching import BucketPolicy
-from .bench import build_runtime, request_images, serve_spec
-from .runtime import ServeRuntime
 
 _T0_ENV = "REPRO_FLEET_T0"
 
 
-def _dirs(cache_dir: str) -> tuple[str, str, str]:
+def _dirs(cache_dir: str) -> tuple[str, str]:
     cache_dir = os.path.abspath(cache_dir)
     return (os.path.join(cache_dir, "registry"),
-            os.path.join(cache_dir, "xla"),
             os.path.join(cache_dir, "study"))
 
 
 def _spec(args):
+    from .bench import serve_spec
+
     # --quick trims the request set, never the net: cold-start numbers are
     # only meaningful for the paper-sized model (a toy net compiles so fast
     # there is nothing for the persistence layer to save)
@@ -67,6 +72,7 @@ def _buckets(args) -> tuple:
 def _build_registry(args, ck_dir: str, study_dir: str, *, save: bool):
     """Build (train if ``--trained``) + warm up + optionally checkpoint."""
     from . import persist
+    from .bench import build_runtime
 
     cache = None
     if args.trained:
@@ -80,13 +86,45 @@ def _build_registry(args, ck_dir: str, study_dir: str, *, save: bool):
     return runtime.registry
 
 
+def run_prepare(args) -> int:
+    """Prepare child: checkpoint the registry if missing, report the
+    platform as one JSON line, exit (releasing the accelerator)."""
+    import jax
+
+    from . import persist
+
+    ck_dir, study_dir = _dirs(args.cache_dir)
+    compile_cache.configure()
+    built = not os.path.exists(os.path.join(ck_dir, persist.MANIFEST))
+    if built:
+        _build_registry(args, ck_dir, study_dir, save=True)
+    devices = jax.devices()
+    print(json.dumps({"platform": devices[0].platform,
+                      "devices": len(devices), "built": built}), flush=True)
+    return 0
+
+
+def replica_limit(platform: str) -> int | None:
+    """Most replica processes the fleet may start (None = no limit).
+
+    On a TPU every unpinned process claims all chips of the host, so two
+    replicas would contend for the same chips: one process at most.
+    """
+    return 1 if platform == "tpu" else None
+
+
 # ---------------------------------------------------------------------------
 # Worker
 # ---------------------------------------------------------------------------
 
 def run_worker(args) -> int:
     """One replica: restore -> warm up -> serve -> report a JSON line."""
+    import numpy as np
+
     from . import persist
+    from .batching import BucketPolicy
+    from .bench import request_images
+    from .runtime import ServeRuntime
 
     # audit: allow[host-sync] cold-start metering: first-response time is
     # charged from parent-side spawn (interpreter + imports included)
@@ -94,8 +132,8 @@ def run_worker(args) -> int:
     t0 = float(os.environ.get(_T0_ENV, now))
     if args.trace:
         obs.enable()
-    ck_dir, xla_dir, study_dir = _dirs(args.cache_dir)
-    compile_cache.configure(xla_dir)
+    ck_dir, study_dir = _dirs(args.cache_dir)
+    compile_cache.configure()
 
     restored = os.path.exists(os.path.join(ck_dir, persist.MANIFEST))
     with obs.span("coldstart.restore", restored=restored):
@@ -161,9 +199,10 @@ def run_worker(args) -> int:
 # Parent
 # ---------------------------------------------------------------------------
 
-def _worker_cmd(args, replica: int) -> list[str]:
-    cmd = [sys.executable, "-m", "repro.serve.fleet", "--worker",
-           "--cache-dir", args.cache_dir, "--replica", str(replica),
+def _child_cmd(args, role: str) -> list[str]:
+    """Command line of a prepare (``--prepare-worker``) or replica child."""
+    cmd = [sys.executable, "-m", "repro.serve.fleet", role,
+           "--cache-dir", args.cache_dir,
            "--requests", str(args.requests), "--seed", str(args.seed),
            "--buckets", args.buckets, "--dataset", args.dataset,
            "--backend", args.backend]
@@ -171,6 +210,11 @@ def _worker_cmd(args, replica: int) -> list[str]:
         cmd.append("--quick")
     if args.trained:
         cmd.append("--trained")
+    return cmd
+
+
+def _worker_cmd(args, replica: int) -> list[str]:
+    cmd = _child_cmd(args, "--worker") + ["--replica", str(replica)]
     if args.trace:
         root, ext = os.path.splitext(args.trace)
         cmd += ["--trace", f"{root}.r{replica}{ext or '.jsonl'}"]
@@ -178,25 +222,42 @@ def _worker_cmd(args, replica: int) -> list[str]:
 
 
 def run_fleet(args) -> int:
-    ck_dir, xla_dir, study_dir = _dirs(args.cache_dir)
-    from . import persist
-
-    compile_cache.configure(xla_dir)
-    if not os.path.exists(os.path.join(ck_dir, persist.MANIFEST)):
-        print(f"fleet: no checkpoint under {ck_dir} — building one", flush=True)
+    # the parent stays off jax: the prepare child touches the accelerator
+    # and exits before any replica needs it
+    try:
         with obs.span("coldstart.prepare"):
-            _build_registry(args, ck_dir, study_dir, save=True)
-        print("fleet: registry checkpoint written", flush=True)
+            prep = subprocess.run(_child_cmd(args, "--prepare-worker"),
+                                  text=True, capture_output=True,
+                                  timeout=args.timeout)
+    except subprocess.TimeoutExpired:
+        print(f"fleet: prepare step exceeded --timeout={args.timeout}s",
+              file=sys.stderr, flush=True)
+        return 124
+    if prep.returncode != 0:
+        sys.stderr.write(prep.stderr)
+        print(f"fleet: prepare step exited {prep.returncode}",
+              file=sys.stderr, flush=True)
+        return prep.returncode or 1
+    info = json.loads(prep.stdout.strip().splitlines()[-1])
+    if info["built"]:
+        print(f"fleet: registry checkpoint written under {args.cache_dir}",
+              flush=True)
     if args.prepare_only:
         return 0
+    limit = replica_limit(info["platform"])
+    if limit is not None and args.replicas > limit:
+        print(f"fleet: refusing to start {args.replicas} replica processes "
+              f"on a {info['platform']} host with {info['devices']} "
+              f"device(s): replicas are not pinned to a chip, so every one "
+              f"would claim all of them; at most {limit} can run here",
+              file=sys.stderr, flush=True)
+        return 2
 
     procs = []
     for i in range(args.replicas):
-        env = dict(os.environ,
-                   **{compile_cache.ENV_DIR: xla_dir,
-                      # audit: allow[host-sync] spawn timestamp: the base of
-                      # each worker's cold-start-to-first-response measure
-                      _T0_ENV: repr(time.time())})
+        # audit: allow[host-sync] spawn timestamp: the base of each
+        # worker's cold-start-to-first-response measure
+        env = dict(os.environ, **{_T0_ENV: repr(time.time())})
         procs.append(subprocess.Popen(
             _worker_cmd(args, i), env=env, text=True,
             stdout=subprocess.PIPE, stderr=subprocess.PIPE))
@@ -242,6 +303,8 @@ def run_fleet(args) -> int:
     ref = results[0]
     for r in results[1:]:
         if r["preds"] != ref["preds"] or r["energies"] != ref["energies"]:
+            from .api import ServeError
+
             raise ServeError(
                 f"replica {r['replica']} disagrees with replica "
                 f"{ref['replica']} on the same request set — preds equal: "
@@ -261,7 +324,7 @@ def main(argv=None) -> int:
     ap.add_argument("--replicas", type=int, default=2)
     ap.add_argument("--cache-dir", required=True,
                     help="shared artifact dir: registry checkpoint, "
-                         "persistent compilation cache, study cache")
+                         "study cache")
     ap.add_argument("--quick", action="store_true",
                     help="CI smoke: small net, small request set")
     ap.add_argument("--requests", type=int, default=16)
@@ -280,6 +343,8 @@ def main(argv=None) -> int:
     ap.add_argument("--prepare-only", action="store_true",
                     help="build + checkpoint the registry, then exit")
     ap.add_argument("--worker", action="store_true", help=argparse.SUPPRESS)
+    ap.add_argument("--prepare-worker", action="store_true",
+                    help=argparse.SUPPRESS)
     ap.add_argument("--build", action="store_true", help=argparse.SUPPRESS)
     ap.add_argument("--save", action="store_true", help=argparse.SUPPRESS)
     ap.add_argument("--replica", type=int, default=0, help=argparse.SUPPRESS)
@@ -287,6 +352,8 @@ def main(argv=None) -> int:
 
     if args.worker:
         return run_worker(args)
+    if args.prepare_worker:
+        return run_prepare(args)
     return run_fleet(args)
 
 

@@ -42,6 +42,7 @@ import json
 import os
 import re
 import tempfile
+import warnings
 
 import jax
 from jax import export as jax_export
@@ -170,6 +171,10 @@ def save_registry(registry: ModelRegistry, root: str, *,
                         blob = _export_plan(handle, int(b))
                     except Exception as e:  # noqa: BLE001 — degrade, don't die
                         obs.counter("persist.plan_export_skipped")
+                        warnings.warn(
+                            f"model {name!r} bucket {b}: plan export failed "
+                            f"({e!r}); a restore will re-lower it",
+                            RuntimeWarning, stacklevel=2)
                         plan_entries[str(int(b))] = {
                             "format": "none", "reason": repr(e)[:200]}
                         continue
@@ -246,11 +251,14 @@ def _restore_plan(handle, root: str, bucket: int, entry: dict) -> bool:
     _register_export_types()
     try:
         exp = jax_export.deserialize(blob)
-    except Exception:  # noqa: BLE001 — version drift: fall back, don't die
+    except Exception as e:  # noqa: BLE001 — version drift: fall back, don't die
         # intact blob that this jax can't re-import (serialization version
         # drift): the handle re-lowers lazily against the warm persistent
         # cache instead — slower first call, identical numbers
         obs.counter("persist.plan_restore_fallback")
+        warnings.warn(f"plan blob {path!r} did not deserialize ({e!r}); "
+                      "the plan will be re-lowered", RuntimeWarning,
+                      stacklevel=2)
         return False
     handle.adopt_plan(bucket, jax.jit(exp.call))
     obs.counter("persist.plan_restore")

@@ -33,7 +33,7 @@ def _rules(findings):
 # ---------------------------------------------------------------------------
 
 def test_dtype_rule_fires_on_f64_promotion():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64():
         closed = jax.make_jaxpr(lambda x: jnp.sin(x) * 2.0)(
             jnp.zeros((3,), jnp.float64))
     found = jaxpr_rules.check_dtypes("fixture", closed, ROOT)

@@ -211,7 +211,8 @@ def test_plan_export_failure_degrades_to_lazy_relower(
 
     monkeypatch.setattr(persist, "_export_plan", boom)
     root = str(tmp_path / "registry")
-    save_registry(reg, root, buckets=BUCKETS)
+    with pytest.warns(RuntimeWarning, match="plan export failed"):
+        save_registry(reg, root, buckets=BUCKETS)
 
     entry = persist.read_manifest(root)["models"]["toy"]
     assert all(p["format"] == "none" for p in entry["plans"].values())
@@ -330,3 +331,35 @@ def test_coldstart_pair_gate_ignores_unpaired_rows(gate):
             "other_bench": {"us_per_call": 9.0}}
     pairs, errors = gate.check_coldstart_pairs(rows, min_speedup=5.0)
     assert pairs == [] and errors == []
+
+
+# ---------------------------------------------------------------------------
+# One process per chip, one compile-cache directory
+# ---------------------------------------------------------------------------
+
+def test_fleet_parent_imports_no_jax():
+    """The fleet parent must stay off the accelerator its replicas need."""
+    import subprocess
+    import sys
+
+    code = "import sys, repro.serve.fleet; print('jax' in sys.modules)"
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, check=True)
+    assert out.stdout.strip() == "False"
+
+
+def test_fleet_starts_one_unpinned_replica_per_tpu_host():
+    from repro.serve import fleet
+
+    assert fleet.replica_limit("tpu") == 1
+    assert fleet.replica_limit("cpu") is None
+
+
+def test_compile_cache_dir_is_jax_variable_or_fixed_default(monkeypatch):
+    from repro.core import compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert compile_cache.resolve_dir() == compile_cache.DEFAULT_DIR
+    assert os.path.basename(compile_cache.DEFAULT_DIR) == ".jax_cache"
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert compile_cache.resolve_dir() == "/elsewhere/cache"

@@ -98,6 +98,39 @@ def test_pallas_backend_is_batch_native_and_non_interpret():
     assert ops.default_spike_impl() in ("xla", "pallas")
 
 
+def _float_contractions(jaxpr):
+    """(primitive, precision) of every float conv/dot in a jaxpr, nested."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ("conv_general_dilated", "dot_general"):
+            if jnp.issubdtype(eqn.invars[1].aval.dtype, jnp.floating):
+                found.append((eqn.primitive.name, eqn.params["precision"]))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _float_contractions(sub)
+    return found
+
+
+@pytest.mark.parametrize("backend", ["dense", "queue_pallas", "queue_ref"])
+def test_plan_contractions_run_at_highest_precision(net, make_snn_config,
+                                                    backend):
+    """Every f32 conv and matmul of a served plan asks for HIGHEST.
+
+    A TPU's default precision rounds f32 operands to bf16, and the
+    thresholds turn that rounding into different spikes; the CPU ignores
+    the setting, so only the program text can show it here.
+    """
+    params, th, _ = net
+    cfg = make_snn_config(SPEC, HW, C, T=2, mode="mttfs_cont",
+                          input_mode="analog")
+    imgs = jnp.zeros((2, HW, HW, C), jnp.float32)
+    closed = jax.make_jaxpr(engine.batch_runner(cfg, backend))(params, th,
+                                                              imgs)
+    found = _float_contractions(closed.jaxpr)
+    assert found, "no float contraction traced"
+    highest = jax.lax.Precision.HIGHEST
+    assert all(p == (highest, highest) for _, p in found), found
+
+
 @pytest.mark.parametrize("B", [1, 3, 16])  # 3, 16: non-divisible + lane-wide
 def test_fused_batched_queue_parity(net, make_snn_config, B):
     """infer_batch(queue_pallas) == per-sample dense AND queue, bit-exact.

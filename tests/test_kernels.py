@@ -168,3 +168,21 @@ def test_kernels_dtype_bf16_event_accum():
                                 vm.astype(jnp.float32), **kw)
     np.testing.assert_allclose(np.asarray(out_k, dtype=np.float32),
                                np.asarray(out_r), atol=0.1)
+
+
+@pytest.mark.parametrize("impl", ["ref", "pallas_interpret",
+                                  "sparse_pallas_interpret"])
+def test_no_oracle_or_interpreter_on_tpu(monkeypatch, impl):
+    """On a TPU only a compiled kernel may run; an oracle or the
+    interpreter standing in for it raises instead."""
+    fmt, occ = _occupancy(6, 1, 1, seed=0)
+    w = jnp.zeros((3, 3, 1, 4), jnp.float32)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="does not run on a TPU"):
+        ops.fused_spike_accum(occ, w, impl=impl, K=3, n_win=fmt.n_win,
+                              bits=fmt.bits_coord, depth=8, H=6, W=6,
+                              invalid=fmt.invalid_word)
+    with pytest.raises(RuntimeError, match="does not run on a TPU"):
+        ops.quant_matmul(jnp.zeros((2, 2), jnp.int8),
+                         jnp.zeros((2, 2), jnp.int8), jnp.float32(1.0),
+                         jnp.float32(1.0), backend="ref")
