@@ -14,7 +14,6 @@ import collections
 import jax
 import jax.numpy as jnp
 
-from .. import obs
 from . import ref as _ref
 from .event_accum import event_accum as _event_accum
 from .moe_gather import moe_gather as _moe_gather
@@ -88,7 +87,6 @@ def fused_spike_accum(occ, weights, *, K, n_win, bits, depth, H, W,
     impl = impl or default_spike_impl()
     _refuse_on_tpu("fused_spike_accum", impl)
     dispatch_counts[f"fused:{impl}"] += 1
-    obs.counter(f"kernels.dispatch.fused:{impl}")
     if impl == "ref":
         if weight_bits is not None:
             return _ref.fused_spike_accum_quant_ref(
@@ -155,7 +153,6 @@ def quant_matmul(a_q, b_q, a_scale, b_scale, *, backend=None, **blocks):
     backend = backend or default_quant_impl()
     _refuse_on_tpu("quant_matmul", backend)
     dispatch_counts[f"quant_matmul:{backend}"] += 1
-    obs.counter(f"kernels.dispatch.quant_matmul:{backend}")
     if backend == "ref":
         return _ref.quant_matmul_ref(a_q, b_q, a_scale, b_scale)
     return _quant_matmul(a_q, b_q, a_scale, b_scale,

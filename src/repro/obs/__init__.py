@@ -1,6 +1,6 @@
 """repro.obs — low-overhead structured tracing + metrics for every layer.
 
-One module-level switch gates everything:
+One module-level switch gates the buffered trace and the metrics:
 
     from repro import obs
     obs.enable()                      # or REPRO_TRACE=/path/trace.jsonl
@@ -10,14 +10,24 @@ One module-level switch gates everything:
     obs.observe("serve.bucket_occupancy", 0.75)
     obs.save_jsonl("trace.jsonl")     # or obs.save_chrome_trace("t.json")
 
+Every span also writes to the profiler: while a ``jax.profiler`` session
+records (``jax.profiler.start_trace`` or a profiler server's capture),
+``span()`` opens a ``jax.profiler.TraceAnnotation`` of the same name, with
+the span's attributes as its arguments, whether or not ``enable()`` is on.
+The span then sits in the profiler's trace on the profiler's clock, next
+to the device ops it waited for.
+
 Design rules (pinned by tests and the ``obs-in-jit`` audit rule):
 
-* **Zero-cost when disabled** — ``span()`` returns one shared no-op
-  object and the metric calls return before touching any lock; the
-  disabled per-span overhead is bounded by ``tests/test_obs.py``.
-* **Host-side only** — obs never imports jax and obs calls are banned
-  inside jit-traced code, so instrumentation can never perturb traced
-  computations or their bit-exactness.
+* **Zero-cost when disabled** — with no profiler session, ``span()``
+  returns one shared no-op object and the metric calls return before
+  touching any lock; the disabled per-span overhead is bounded by
+  ``tests/test_obs.py``.
+* **Host-side only** — obs is imported without jax (it looks up the
+  profiler's annotation on a span, and only once the process has
+  imported jax), and obs calls are banned inside jit-traced code, so
+  instrumentation can never perturb traced computations or their
+  bit-exactness.
 * **Deterministic under test** — ``enable(clock=...)`` injects the time
   source used for every span/event timestamp.
 
@@ -30,24 +40,27 @@ from __future__ import annotations
 
 import atexit
 import os
+import sys
 import time
 from typing import Any, Callable, Dict, List, Optional
 
 from . import export
 from .metrics import DEFAULT_QS, Histogram, Metrics, percentiles
-from .trace import NOOP_SPAN, EventRecord, NoopSpan, Span, SpanRecord, Tracer
+from .trace import (NOOP_SPAN, EventRecord, NoopSpan, ProfilerSpan, Span,
+                    SpanRecord, Tracer)
 
 __all__ = [
     "enable", "disable", "enabled", "reset", "span", "event", "counter",
     "gauge", "observe", "spans", "events", "metrics_snapshot",
     "save_jsonl", "save_chrome_trace", "percentiles", "Histogram",
     "Metrics", "Tracer", "SpanRecord", "EventRecord", "Span", "NoopSpan",
-    "NOOP_SPAN", "DEFAULT_QS",
+    "ProfilerSpan", "NOOP_SPAN", "DEFAULT_QS",
 ]
 
 _enabled: bool = False
 _tracer: Tracer = Tracer()
 _metrics: Metrics = Metrics()
+_annotation_cls = None   # jax.profiler.TraceAnnotation, once jax is imported
 
 
 def enable(clock: Optional[Callable[[], float]] = None) -> None:
@@ -75,11 +88,30 @@ def reset() -> None:
     _metrics.clear()
 
 
+def _annotation(name: str, attrs: Dict[str, Any]):
+    """The profiler's annotation for a span, or None when no profiler
+    session records (a TraceMe started then would record nothing)."""
+    global _annotation_cls
+    cls = _annotation_cls
+    if cls is None:
+        if "jax" not in sys.modules:
+            return None     # no jax, no device trace to join
+        from jax.profiler import TraceAnnotation
+
+        cls = _annotation_cls = TraceAnnotation
+    if not cls.is_enabled():
+        return None
+    return cls(name, **attrs)
+
+
 def span(name: str, **attrs: Any):
-    """Context manager timing a named region. No-op when disabled."""
+    """Context manager timing a named region, in the buffered trace when
+    enabled and in the profiler's trace while a profiler session records.
+    No-op when neither."""
+    ann = _annotation(name, attrs)
     if not _enabled:
-        return NOOP_SPAN
-    return _tracer.span(name, **attrs)
+        return NOOP_SPAN if ann is None else ProfilerSpan(ann)
+    return _tracer.span(name, ann, **attrs)
 
 
 def event(name: str, **attrs: Any) -> None:

@@ -7,7 +7,11 @@ thread trace concurrently; finished spans are appended to one shared,
 lock-guarded buffer.
 
 The clock is injectable (any ``() -> float`` in seconds) so tests can pin
-exact durations; the default is ``time.perf_counter``.  Everything here is
+exact durations; the default is ``time.perf_counter``.  While a
+``jax.profiler`` session records, a span also carries the profiler's
+annotation of the same name (``ann``: a context manager with
+``set_metadata``), which it opens around its own clock reads, so the span
+lands in the profiler's trace beside the device ops.  Everything here is
 plain host-side Python — this module must never be imported *into* traced
 code (the ``obs-in-jit`` audit rule enforces that at the call sites).
 """
@@ -74,9 +78,11 @@ class Span:
     """A live span; use as a context manager. ``set()`` adds attributes
     after entry (e.g. a batch size known only mid-span)."""
 
-    __slots__ = ("_tracer", "name", "attrs", "sid", "parent", "depth", "_t0")
+    __slots__ = ("_tracer", "name", "attrs", "sid", "parent", "depth", "_t0",
+                 "_ann")
 
-    def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any]):
+    def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any],
+                 ann: Any = None):
         self._tracer = tracer
         self.name = name
         self.attrs = attrs
@@ -84,12 +90,17 @@ class Span:
         self.parent = -1
         self.depth = 0
         self._t0 = 0.0
+        self._ann = ann
 
     def set(self, **attrs: Any) -> "Span":
         self.attrs.update(attrs)
+        if self._ann is not None:
+            self._ann.set_metadata(**attrs)
         return self
 
     def __enter__(self) -> "Span":
+        if self._ann is not None:
+            self._ann.__enter__()
         tr = self._tracer
         stack = tr._stack()
         self.sid = next(tr._ids)
@@ -111,6 +122,8 @@ class Span:
             sid=self.sid, parent=self.parent, name=self.name,
             t0=self._t0, t1=t1, depth=self.depth,
             tid=threading.get_ident(), attrs=self.attrs))
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         return False
 
 
@@ -131,6 +144,28 @@ class NoopSpan:
 
 
 NOOP_SPAN = NoopSpan()
+
+
+class ProfilerSpan:
+    """A span while tracing is disabled but a profiler session records:
+    only the profiler's annotation, nothing buffered."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, ann: Any):
+        self._ann = ann
+
+    def __enter__(self) -> "ProfilerSpan":
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc: Any) -> bool:
+        self._ann.__exit__(*exc)
+        return False
+
+    def set(self, **attrs: Any) -> "ProfilerSpan":
+        self._ann.set_metadata(**attrs)
+        return self
 
 
 class Tracer:
@@ -154,8 +189,8 @@ class Tracer:
         with self._lock:
             self._spans.append(rec)
 
-    def span(self, name: str, **attrs: Any) -> Span:
-        return Span(self, name, attrs)
+    def span(self, name: str, ann: Any = None, /, **attrs: Any) -> Span:
+        return Span(self, name, attrs, ann)
 
     def event(self, name: str, **attrs: Any) -> None:
         rec = EventRecord(name=name, ts=self.clock(),

@@ -169,13 +169,23 @@ class ModelHandle:
 
     def run_bucket(self, images, n_valid: int):
         """Execute one padded bucket; return the valid prefix (see engine
-        mask contract). ``images`` is the already-padded (B, H, W, C) array."""
-        logits, stats = self.plan_for(images.shape[0])(
-            self.params, self.thresholds, jnp.asarray(images))
-        # audit: allow[host-sync] serve latency metering: the response
-        # timestamp must not be taken before the device is done
-        jax.block_until_ready(logits)
-        return engine.slice_valid(logits, stats, n_valid)
+        mask contract). ``images`` is the already-padded (B, H, W, C) array.
+
+        Its phases are ``obs`` spans: ``serve.upload`` (the batch to the
+        device), ``serve.dispatch`` (the plan's lookup and call, until it
+        returns), ``serve.wait`` (until the device is done) and
+        ``serve.slice`` (the valid prefix)."""
+        with obs.span("serve.upload", bytes=images.nbytes):
+            images = jnp.asarray(images)
+        with obs.span("serve.dispatch"):
+            logits, stats = self.plan_for(images.shape[0])(
+                self.params, self.thresholds, images)
+        with obs.span("serve.wait"):
+            # audit: allow[host-sync] serve latency metering: the response
+            # timestamp must not be taken before the device is done
+            jax.block_until_ready(logits)
+        with obs.span("serve.slice"):
+            return engine.slice_valid(logits, stats, n_valid)
 
     def warmup(self, buckets=DEFAULT_BUCKETS) -> None:
         """Compile (and once-execute) each bucket so serving never traces.

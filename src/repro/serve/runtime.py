@@ -140,119 +140,139 @@ class ServeRuntime:
         ``now`` is the batch launch time for queue-wait accounting; leave
         it None to read ``self.clock()`` (virtual-clock benches pass their
         simulated time instead).
+
+        Each phase is an ``obs`` span, one per step, nested as
+        ``serve.step`` > ``serve.form``, ``serve.execute`` (> ``upload``,
+        ``dispatch``, ``wait``, ``slice`` in ``ModelHandle.run_bucket``),
+        ``serve.fetch``, ``serve.price``, ``serve.respond``; under a
+        ``jax.profiler`` session they split the device's idle gaps by
+        what the step was doing (docs/OBSERVABILITY.md).
         """
         if not self.queue:
             return []
-        t_step0 = self.clock()
-        model = self._next_model()
-        try:
-            handle = self.registry.get(model)
-        except ServeError:
-            # the model was LRU-evicted since submit. Reject ITS queued
-            # requests loudly (the error names every dropped rid) but keep
-            # the rest of the queue intact — one dead model must neither
-            # silently lose work nor wedge serving for the healthy ones
-            dead = [r.rid for r in self.queue if r.model == model]
-            self.queue = collections.deque(
-                r for r in self.queue if r.model != model)
-            self._pending.pop(model, None)
-            raise ServeError(
-                f"model {model!r} is no longer registered; rejected its "
-                f"queued request(s) rid={dead} (other models' requests "
-                "remain queued)") from None
-        taken = self._take_batch(model)
-        self._last_model = model
-        bucket = self.policy.select(len(taken))
-        if bucket < len(taken):
-            # the policy rounded down (serve a full bucket now rather than
-            # pad past half): requeue the tail at the front, order intact
-            self.queue.extendleft(reversed(taken[bucket:]))
-            taken = taken[:bucket]
-        padded = self.policy.pad(np.stack([r.image for r in taken]), bucket)
+        with obs.span("serve.step") as span:
+            t_step0 = self.clock()
+            with obs.span("serve.form"):
+                model = self._next_model()
+                try:
+                    handle = self.registry.get(model)
+                except ServeError:
+                    # the model was LRU-evicted since submit. Reject ITS
+                    # queued requests loudly (the error names every dropped
+                    # rid) but keep the rest of the queue intact — one dead
+                    # model must neither silently lose work nor wedge
+                    # serving for the healthy ones
+                    dead = [r.rid for r in self.queue if r.model == model]
+                    self.queue = collections.deque(
+                        r for r in self.queue if r.model != model)
+                    self._pending.pop(model, None)
+                    raise ServeError(
+                        f"model {model!r} is no longer registered; rejected "
+                        f"its queued request(s) rid={dead} (other models' "
+                        "requests remain queued)") from None
+                taken = self._take_batch(model)
+                self._last_model = model
+                bucket = self.policy.select(len(taken))
+                if bucket < len(taken):
+                    # the policy rounded down (serve a full bucket now rather
+                    # than pad past half): requeue the tail at the front, order
+                    # intact
+                    self.queue.extendleft(reversed(taken[bucket:]))
+                    taken = taken[:bucket]
+                padded = self.policy.pad(np.stack([r.image for r in taken]),
+                                         bucket)
+            span.set(bucket=bucket, valid=len(taken))
 
-        # three telescoping clock reads bound the step's phases exactly:
-        # [t_step0, t_exec0) batch-form, [t_exec0, t_exec1) execute,
-        # [t_exec1, t_done) price + response assembly. Their sum IS the
-        # step total, so the per-request breakdown accounts for the whole
-        # measured latency (pinned by tests/test_obs.py).
-        t_exec0 = self.clock()
-        launch = t_exec0 if now is None else now
-        with obs.span("serve.execute", model=model, bucket=bucket,
-                      valid=len(taken)):
-            logits, stats = handle.run_bucket(padded, len(taken))
-        t_exec1 = self.clock()
-        service_s = t_exec1 - t_exec0
-        batch_form_s = t_exec0 - t_step0
-        pad_fraction = (bucket - len(taken)) / bucket
+            # three telescoping clock reads bound the step's phases
+            # exactly: [t_step0, t_exec0) batch-form, [t_exec0, t_exec1)
+            # execute, [t_exec1, t_done) fetch, price + response assembly.
+            # Their sum IS the step total, so the per-request breakdown
+            # accounts for the whole measured latency (pinned by
+            # tests/test_obs.py).
+            t_exec0 = self.clock()
+            launch = t_exec0 if now is None else now
+            with obs.span("serve.execute", model=model, bucket=bucket,
+                          valid=len(taken)):
+                logits, stats = handle.run_bucket(padded, len(taken))
+            t_exec1 = self.clock()
+            service_s = t_exec1 - t_exec0
+            batch_form_s = t_exec0 - t_step0
+            pad_fraction = (bucket - len(taken)) / bucket
 
-        self._pending[model] -= len(taken)
-        self.n_batches += 1
-        self.n_served += len(taken)
-        self.n_padded_slots += bucket - len(taken)
-        self.bucket_histogram[bucket] += 1
-        if obs.enabled():
-            obs.observe("serve.bucket_occupancy", len(taken) / bucket)
-            obs.observe("serve.pad_fraction", pad_fraction)
-            obs.counter("serve.batches")
-            obs.counter("serve.requests", len(taken))
+            self._pending[model] -= len(taken)
+            self.n_batches += 1
+            self.n_served += len(taken)
+            self.n_padded_slots += bucket - len(taken)
+            self.bucket_histogram[bucket] += 1
+            if obs.enabled():
+                obs.observe("serve.bucket_occupancy", len(taken) / bucket)
+                obs.counter("serve.batches")
+                obs.counter("serve.requests", len(taken))
 
-        logits = np.asarray(logits)
-        ev = np.asarray(stats.events_in)
-        sp = np.asarray(stats.spikes_out)
-        ao = np.asarray(stats.add_ops)
-        qw = np.asarray(stats.queue_words)
-        ovf = np.asarray(stats.overflow)
+            with obs.span("serve.fetch") as fetch:
+                logits = np.asarray(logits)
+                ev = np.asarray(stats.events_in)
+                sp = np.asarray(stats.spikes_out)
+                ao = np.asarray(stats.add_ops)
+                qw = np.asarray(stats.queue_words)
+                ovf = np.asarray(stats.overflow)
+                fetch.set(bytes=sum(a.nbytes
+                                    for a in (logits, ev, sp, ao, qw, ovf)))
 
-        # price the whole batch in ONE price_record call (repricing is
-        # elementwise per sample, so row i of a batch pricing bit-equals
-        # pricing row i alone — and per-request jnp dispatch overhead would
-        # otherwise dominate small-model serving cost)
-        batch_record = StatsRecord(events_in=ev, spikes_out=sp, add_ops=ao,
-                                   queue_words=qw, overflow=ovf)
-        with obs.span("serve.price", model=model, valid=len(taken)):
-            e = price_record(batch_record, input_hw=handle.cfg.input_hw,
-                             compressed=handle.cfg.compressed,
-                             vmem_resident=handle.vmem_resident)
-        energy_j = np.asarray(e.total_j)
-        model_latency_s = np.asarray(e.latency_s)
+            # price the whole batch in ONE price_record call (repricing is
+            # elementwise per sample, so row i of a batch pricing bit-equals
+            # pricing row i alone — and per-request jnp dispatch overhead
+            # would otherwise dominate small-model serving cost)
+            batch_record = StatsRecord(events_in=ev, spikes_out=sp,
+                                       add_ops=ao, queue_words=qw,
+                                       overflow=ovf)
+            with obs.span("serve.price", model=model, valid=len(taken)):
+                e = price_record(batch_record, input_hw=handle.cfg.input_hw,
+                                 compressed=handle.cfg.compressed,
+                                 vmem_resident=handle.vmem_resident)
+                energy_j = np.asarray(e.total_j)
+                model_latency_s = np.asarray(e.latency_s)
 
-        responses = []
-        for i, req in enumerate(taken):
-            row = StatsRecord(
-                events_in=ev[i : i + 1], spikes_out=sp[i : i + 1],
-                add_ops=ao[i : i + 1], queue_words=qw[i : i + 1],
-                overflow=ovf[i : i + 1])
-            responses.append(InferResponse(
-                rid=req.rid, model=req.model, logits=logits[i],
-                pred=int(np.argmax(logits[i])), stats=row,
-                energy_j=float(energy_j[i]),
-                model_latency_s=float(model_latency_s[i]),
-                bucket=bucket, batch_valid=len(taken),
-                queue_wait_s=max(0.0, launch - req.arrival_s),
-                service_s=service_s, batch_form_s=batch_form_s,
-                pad_fraction=pad_fraction))
-        # the price window closes only after responses exist, so these two
-        # fields are assigned post-construction (the dataclass is mutable)
-        t_done = self.clock()
-        price_s = t_done - t_exec1
-        step_total_s = t_done - t_step0
-        for resp in responses:
-            resp.price_s = price_s
-            resp.step_total_s = step_total_s
-        if obs.enabled():
-            for resp in responses:
-                # waterfall segments must not overlap: queue_wait_s
-                # (admission -> launch) already contains the batch-form
-                # window, so the event's queue segment stops at t_step0
-                wf_queue = max(0.0, resp.queue_wait_s - batch_form_s)
-                obs.event(
-                    "serve.request", rid=resp.rid, model=resp.model,
-                    bucket=resp.bucket, pad_fraction=resp.pad_fraction,
-                    queue_wait_s=wf_queue, batch_form_s=batch_form_s,
-                    execute_s=service_s, price_s=price_s,
-                    latency_s=wf_queue + batch_form_s + service_s + price_s)
-                obs.observe("serve.request_latency_s", resp.latency_s)
-        return responses
+            with obs.span("serve.respond"):
+                responses = []
+                for i, req in enumerate(taken):
+                    row = StatsRecord(
+                        events_in=ev[i : i + 1], spikes_out=sp[i : i + 1],
+                        add_ops=ao[i : i + 1], queue_words=qw[i : i + 1],
+                        overflow=ovf[i : i + 1])
+                    responses.append(InferResponse(
+                        rid=req.rid, model=req.model, logits=logits[i],
+                        pred=int(np.argmax(logits[i])), stats=row,
+                        energy_j=float(energy_j[i]),
+                        model_latency_s=float(model_latency_s[i]),
+                        bucket=bucket, batch_valid=len(taken),
+                        queue_wait_s=max(0.0, launch - req.arrival_s),
+                        service_s=service_s, batch_form_s=batch_form_s,
+                        pad_fraction=pad_fraction))
+                # the price window closes only after responses exist, so
+                # these two fields are assigned post-construction (the
+                # dataclass is mutable)
+                t_done = self.clock()
+                price_s = t_done - t_exec1
+                step_total_s = t_done - t_step0
+                for resp in responses:
+                    resp.price_s = price_s
+                    resp.step_total_s = step_total_s
+            if obs.enabled():
+                for resp in responses:
+                    # waterfall segments must not overlap: queue_wait_s
+                    # (admission -> launch) already contains the batch-form
+                    # window, so the event's queue segment stops at t_step0
+                    wf_queue = max(0.0, resp.queue_wait_s - batch_form_s)
+                    obs.event(
+                        "serve.request", rid=resp.rid, model=resp.model,
+                        bucket=resp.bucket, pad_fraction=resp.pad_fraction,
+                        queue_wait_s=wf_queue, batch_form_s=batch_form_s,
+                        execute_s=service_s, price_s=price_s,
+                        latency_s=(wf_queue + batch_form_s + service_s
+                                   + price_s))
+                    obs.observe("serve.request_latency_s", resp.latency_s)
+            return responses
 
     def run_until_drained(self, max_steps: int = 100_000):
         """Step until the queue is empty; responses in completion order.

@@ -15,9 +15,13 @@ What is pinned here, in the order the ISSUE's acceptance criteria state it:
 5. **Injectable clock** — two runs under the same fake clock produce
    identical records (determinism under test).
 6. **Serving neutrality** — logits and energies of a served batch are
-   bit-exact with tracing on vs off, and the per-request breakdown
-   telescopes to the measured step total.
+   bit-exact with tracing on vs off, and with a profiler session on vs
+   off, and the per-request breakdown telescopes to the measured step
+   total.
+7. **Profiler sink** — under ``jax.profiler`` every serve step writes its
+   nine phase spans into the profiler's trace, once per step, nested.
 """
+import glob
 import json
 import time
 
@@ -271,19 +275,98 @@ def _serve_batch(imgs, *, traced):
     return runtime.run_until_drained()
 
 
-def test_tracing_is_bit_exact_neutral_on_serve_responses():
+def _profiled(log_dir, fn, *args, **kwargs):
+    """``fn(...)`` under a ``jax.profiler`` session writing to ``log_dir``."""
+    jax.profiler.start_trace(str(log_dir))
+    try:
+        return fn(*args, **kwargs)
+    finally:
+        jax.profiler.stop_trace()
+
+
+def _profiler_spans(log_dir):
+    """[(name, start_ns, end_ns, stats)] of the host's ``serve.*`` events."""
+    (path,) = glob.glob(str(log_dir / "**" / "*.xplane.pb"), recursive=True)
+    out = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                out.extend((ev.name, ev.start_ns, ev.end_ns, dict(ev.stats))
+                           for ev in line.events
+                           if ev.name.startswith("serve."))
+    return sorted(out, key=lambda s: s[1])
+
+
+@pytest.mark.parametrize("sink", ["obs", "profiler"])
+def test_tracing_is_bit_exact_neutral_on_serve_responses(sink, tmp_path):
     imgs = np.random.default_rng(11).random((5, HW, HW, C)).astype(np.float32)
     off = sorted(_serve_batch(imgs, traced=False), key=lambda r: r.rid)
-    on = sorted(_serve_batch(imgs, traced=True), key=lambda r: r.rid)
+    if sink == "obs":
+        on = _serve_batch(imgs, traced=True)
+    else:
+        on = _profiled(tmp_path, _serve_batch, imgs, traced=False)
+    on = sorted(on, key=lambda r: r.rid)
+    assert [r.rid for r in on] == [r.rid for r in off]
     for a, b in zip(off, on):
         np.testing.assert_array_equal(a.logits, b.logits)
         assert a.pred == b.pred
         assert np.float32(a.energy_j) == np.float32(b.energy_j)
+        np.testing.assert_array_equal(a.stats.events_in, b.stats.events_in)
+    if sink == "profiler":
+        # the profiled run reached the profiler's trace, and obs's own
+        # buffer stayed empty: the profiler sink needs no obs.enable()
+        steps = [s for s in _profiler_spans(tmp_path) if s[0] == "serve.step"]
+        assert len(steps) == 2
+        assert obs.spans() == [] and obs.events() == []
+        return
     # and the traced run actually recorded the serve story
     names = {s.name for s in obs.spans()}
     assert {"serve.execute", "serve.price"} <= names
     assert obs.metrics_snapshot()["counters"]["serve.requests"] == 5
     assert [e.name for e in obs.events()].count("serve.request") == 5
+
+
+#: the serve step's phase spans, each with its parent
+PHASES = {"serve.step": None, "serve.form": "serve.step",
+          "serve.execute": "serve.step", "serve.upload": "serve.execute",
+          "serve.dispatch": "serve.execute", "serve.wait": "serve.execute",
+          "serve.slice": "serve.execute", "serve.fetch": "serve.step",
+          "serve.price": "serve.step", "serve.respond": "serve.step"}
+
+
+@pytest.mark.parametrize("obs_on", [False, True])
+def test_serve_step_phases_reach_the_profiler_trace(obs_on, tmp_path):
+    """Each phase span once per step, inside its parent and in order, on
+    the profiler's clock; the step carries its bucket and valid count."""
+    imgs = np.random.default_rng(2).random((5, HW, HW, C)).astype(np.float32)
+    responses = _profiled(tmp_path, _serve_batch, imgs, traced=obs_on)
+    assert len(responses) == 5
+    spans = [s for s in _profiler_spans(tmp_path) if s[0] in PHASES]
+    steps = [s for s in spans if s[0] == "serve.step"]
+    # the ladder (1, 4) serves 5 requests as a 4-bucket, then a 1-bucket
+    assert [(s[3]["bucket"], s[3]["valid"]) for s in steps] == [(4, 4),
+                                                                 (1, 1)]
+    for _, t0, t1, _ in steps:
+        inside = [s for s in spans if t0 <= s[1] and s[2] <= t1]
+        assert sorted(s[0] for s in inside) == sorted(PHASES)
+        by = {s[0]: s for s in inside}
+        for name, parent in PHASES.items():
+            if parent is not None:
+                assert by[parent][1] <= by[name][1] <= by[name][2] \
+                    <= by[parent][2], name
+        order = ["serve.form", "serve.upload", "serve.dispatch", "serve.wait",
+                 "serve.slice", "serve.fetch", "serve.price", "serve.respond"]
+        assert [by[n][1] for n in order] == sorted(by[n][1] for n in order)
+    execs = [s for s in spans if s[0] == "serve.execute"]
+    assert [(s[3]["bucket"], s[3]["valid"]) for s in execs] == [(4, 4), (1, 1)]
+    assert all(s[3]["bytes"] > 0 for s in spans
+               if s[0] in ("serve.upload", "serve.fetch"))
+    if obs_on:
+        # the buffered trace holds the same spans, with the same attributes
+        rec = [s for s in obs.spans() if s.name in PHASES]
+        assert sorted(s.name for s in rec) == sorted(s[0] for s in spans)
+        assert [s.attrs for s in rec if s.name == "serve.step"] == [
+            {"bucket": 4, "valid": 4}, {"bucket": 1, "valid": 1}]
 
 
 def test_breakdown_telescopes_to_step_total_and_event_latency():
