@@ -203,9 +203,8 @@ def trace_pallas_kernels(cfg: engine.SNNConfig | None = None) -> dict:
     N = B_PROBE * cfg.T
     occ = jnp.zeros((N, cp.in_c, K2, P), jnp.int32)
     w = jnp.zeros((K, K, cp.in_c, cp.out_c), jnp.float32)
-    geo = dict(K=K, n_win=cp.fmt.n_win, bits=cp.fmt.bits_coord,
-               depth=cfg.depth, H=cp.in_hw, W=cp.in_hw,
-               invalid=cp.fmt.invalid_word)
+    geo = dict(K=K, n_win=cp.fmt.n_win, depth=cfg.depth, H=cp.in_hw,
+               W=cp.in_hw)
     words = jnp.zeros((cp.in_c, K2, cfg.depth), jnp.int32)
     counts = jnp.zeros((cp.in_c, K2), jnp.int32)
     vm = jnp.zeros((cp.in_hw, cp.in_hw, cp.out_c), jnp.float32)
@@ -216,7 +215,8 @@ def trace_pallas_kernels(cfg: engine.SNNConfig | None = None) -> dict:
                 sp.fused_spike_accum_pallas, **geo))(occ, w))
         traces["kernels.spike_sparse.fused_spike_accum_sparse_pallas"] = (
             jax.make_jaxpr(functools.partial(
-                ss.fused_spike_accum_sparse_pallas, **geo))(occ, w))
+                ss.fused_spike_accum_sparse_pallas, bits=cp.fmt.bits_coord,
+                invalid=cp.fmt.invalid_word, **geo))(occ, w))
         traces["kernels.event_accum.event_accum"] = (
             jax.make_jaxpr(functools.partial(
                 ea.event_accum, K=K, n_win=cp.fmt.n_win,

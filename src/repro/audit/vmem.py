@@ -53,7 +53,7 @@ def paper_geometries(depth: int = DEFAULT_DEPTH):
         for cp in plan.convs:
             yield dataset, dict(K=cp.kernel, n_win=cp.fmt.n_win,
                                 depth=depth, H=cp.in_hw, W=cp.in_hw,
-                                C_out=cp.out_c)
+                                C_in=cp.in_c, C_out=cp.out_c)
 
 
 def module_anchor(module, root: str) -> tuple[str, int]:

@@ -83,6 +83,8 @@ def fused_spike_accum(occ, weights, *, K, n_win, bits, depth, H, W,
     optional ragged ``n_rows``) — do work proportional to occupancy and
     additionally accept ``weight_bits`` for the int-quantized accumulate
     (also honored by 'ref', which then anchors the quant parity tests).
+    ``bits``, ``invalid`` (the AE word format) and ``seg`` (its queue
+    segment) reach only the sparse Pallas kernel, the one scalar event walk.
     """
     impl = impl or default_spike_impl()
     _refuse_on_tpu("fused_spike_accum", impl)
@@ -113,9 +115,8 @@ def fused_spike_accum(occ, weights, *, K, n_win, bits, depth, H, W,
         return _fused_xla(occ, weights, K=K, n_win=n_win, depth=depth,
                           H=H, W=W)
     if impl in ("pallas", "pallas_interpret"):
-        return _fused_pallas(occ, weights, K=K, n_win=n_win, bits=bits,
-                             depth=depth, H=H, W=W, invalid=invalid, seg=seg,
-                             interpret=(impl == "pallas_interpret"))
+        return _fused_pallas(occ, weights, K=K, n_win=n_win, depth=depth,
+                             H=H, W=W, interpret=(impl == "pallas_interpret"))
     raise ValueError(
         f"unknown fused_spike_accum impl {impl!r} "
         "(expected 'xla', 'pallas', 'pallas_interpret', 'sparse', "

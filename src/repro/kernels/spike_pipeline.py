@@ -1,4 +1,4 @@
-"""Fused, batch-native Pallas spike pipeline: compaction + accumulation.
+"""Fused, batch-native Pallas spike pipeline: drop rule + accumulation.
 
 PR 1 split the event path into two kernels with an HBM round-trip between
 them: ``spike_compact`` (Thresholding Unit encoder: occupancy -> packed AE
@@ -7,24 +7,28 @@ words) and ``event_accum`` (queue words -> membrane charge). DeepFire2
 wins from *not* doing that — the event stream stays on-chip between the
 encoder and the accumulator. This module is that fusion:
 
-    per-phase occupancy ──compact──▶ AE words in SMEM ──accumulate──▶ charge
-                          (never leaves the chip)
+    per-phase occupancy ──drop rule──▶ surviving spike maps ──drain──▶ charge
+                                        (VMEM, one block per row)
 
-Design points (vs. the unfused kernels):
+Design points:
 
 - **Batch axis in the kernel grid.** The grid is ``(N, C_in)`` where
   ``N = B * T`` — every (sample, time-step) segment is an independent grid
   row. Queue backends previously reached batch > 1 only through an outer
   ``jax.vmap`` of the whole single-sample program.
-- **Double-buffered fixed-depth segments.** The queue depth ``D`` is split
-  into segments of ``seg`` words held in a ``2·K²·seg``-word SMEM scratch:
-  while segment ``s`` drains into the membrane map, segment ``s+1`` is
-  compacted into the other buffer — the paper's Fig. 3 segmented AEQ as a
-  software pipeline. The packed words never materialize in HBM.
+- **A vector drain over the interlaced banks.** The K² phase queues of a
+  channel are the paper's memory interlacing: the events of one phase lie
+  K apart, so at one kernel offset no two of them touch the same membrane
+  pixel. The kernel keeps the membrane interlaced, one bank per phase, and
+  adds each (phase, offset) pair's events as one masked vector update of
+  ``spikes ⊗ w[dy, dx, :]`` over the whole window grid: K⁴ slab updates
+  per (row, channel) cell, none per event. A phase whose queue is empty is
+  skipped; that skip is the event-driven part of the work. The membrane
+  is de-interlaced once, after the last input channel.
 - **Same drop semantics as ``core.aeq.compact_spikes``.** Events beyond
   ``depth`` per (channel, phase) queue are dropped in window-row-major
-  order, so overflow counts and the surviving event set are bit-identical
-  to the unfused AEQ model.
+  order (a prefix count, statically elided when ``depth >= P``), so the
+  surviving event set is bit-identical to the unfused AEQ model.
 
 Three interchangeable implementations (differentially tested):
 
@@ -56,6 +60,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..audit.contracts import KernelContract
+from ..core.aeq import segment_keep
 from ..core.snn_layers import PRECISION
 
 # Declared resource/dtype intent, verified by ``python -m repro.audit``
@@ -65,210 +70,137 @@ CONTRACT = KernelContract(name="fused_spike_accum_pallas",
                           module=__name__, accum_dtype="float32")
 
 
-def vmem_blocks(*, K, H, W, C_out, **_unused):
+def vmem_blocks(*, K, n_win, H, W, C_in, C_out, **_unused):
     """Per-grid-cell resident buffers as data, for ``audit.vmem``.
 
-    Mirrors :func:`fused_spike_accum_pallas`'s VMEM BlockSpecs exactly —
-    ``(name, block shape, bytes per element, double-buffered)``; pipelined
-    in/out blocks are double-buffered by the Mosaic emitter. The occupancy
-    block and the queue segments live in SMEM (:func:`kernel_specs`).
+    Mirrors :func:`fused_spike_accum_pallas`'s VMEM BlockSpecs and scratch
+    exactly — ``(name, block shape, bytes per element, double-buffered)``;
+    pipelined in/out blocks are double-buffered by the Mosaic emitter. The
+    per-phase event counts live in SMEM (:func:`fused_spike_accum_pallas`).
     """
+    wx = _win_cols(n_win)
     return [
+        ("spike_block", (K * K, n_win, wx, C_in), 4, True),
         ("w_block", (K, K, C_out), 4, True),
         ("out_block", (H, W, C_out), 4, True),
+        ("membrane", (K, K, n_win + 2, wx, C_out), 4, False),
     ]
 
 
-def _default_seg(depth: int, n_win: int) -> int:
-    """Segment length: <= depth, <= the max events a phase can hold (P)."""
-    return max(1, min(64, depth, n_win * n_win))
+def _win_cols(n_win: int) -> int:
+    """Window columns of a bank with its one-window halo, to whole sublanes."""
+    return -(-(n_win + 2) // 8) * 8
 
 
 # ---------------------------------------------------------------------------
 # Pallas TPU kernel
 # ---------------------------------------------------------------------------
 #
-# Mosaic layout of one grid cell:
+# Mosaic layout of one grid cell (row n, input channel c):
 #
-# - the (K2, P) occupancy block sits in SMEM, flattened to (1, K2*P) so its
-#   two minor dimensions are full: the encoder reads it one scalar at a time
-#   (a vector walk over positions would be a load at a dynamic lane offset);
-# - the AE queue segments are SMEM scratch, written and read as scalars;
-# - the (K, K, C_out) weight slice and the (H, W, C_out) membrane map are
-#   VMEM blocks whose two minor dimensions are full, and each event updates
-#   one C_out-wide row of the map.
+# - the spike maps of the K2 phases sit in one VMEM block (K2, n_win, wx,
+#   C_in): window row leading, window column on sublanes (one zero column
+#   of halo before it), channels on lanes. The block index ignores c, so
+#   it is fetched once per row; channel c is picked by a lane compare and
+#   a lane reduction, and broadcast back across the C_out lanes;
+# - the membrane is a VMEM scratch in interlaced layout (K, K, n_win + 2,
+#   wx, C_out): bank (ry, rx) holds pixels (wy * K + ry, wx * K + rx) at
+#   [wy + 1, wx + 1], so every (phase, offset) update is one slab of whole
+#   tiles, shifted by at most one window (a leading index for rows, a
+#   sublane roll for columns) and cut by the halo;
+# - the per-(channel, phase) event counts sit in SMEM, one row per n;
+# - the (K, K, C_out) weight slice is a VMEM block, one row per offset.
 
-def _pipeline(occ_ref, w_ref, cur_ref, buf_ref, *, n_pos, n_seg, K, n_win,
-              bits, depth, seg, H, W, invalid):
-    """Compact + accumulate one (sample*step, channel) cell.
+def _kernel(cnt_ref, spk_ref, w_ref, out_ref, mem_ref, *, K, n_win, H, W):
+    """Accumulate channel c of row n; de-interlace after the last channel.
 
-    ``n_pos`` bounds the encoder's position walk and ``n_seg(totals)`` the
-    segment loop; both may be traced scalars (the gated kernel in
-    ``spike_sparse`` passes occupancy-derived bounds).
+    Each phase's events lie K apart, so for one kernel offset no two of
+    them reach the same membrane pixel: the phase's whole contribution at
+    that offset is one conflict-free masked vector update. A phase with no
+    event is skipped.
     """
+    c = pl.program_id(1)
     K2 = K * K
-    P = n_win * n_win
     pad = K // 2
-    mask = (1 << bits) - 1
+    _, _, wx, C_in = spk_ref.shape
+    C_out = mem_ref.shape[-1]
 
-    def slot(bs, ph, i):
-        return (bs * K2 + ph) * seg + i
-
-    def fill(s, bs):
-        """Compact events with queue slot in [s*seg, (s+1)*seg) into buf[bs].
-
-        Sequential append with a running per-phase count — the hardware
-        queue write pointer, exactly as in kernels/spike_compact.py, but
-        the destination is on-chip scratch instead of an HBM output.
-        Returns the per-phase totals capped at ``depth`` (identical to
-        AEQ.counts for this segment set): the drain-side liveness bound.
-        """
-        base = s * seg
-
-        def clear(i, _):
-            for ph in range(K2):
-                buf_ref[slot(bs, ph, i)] = jnp.int32(invalid)
-            return 0
-
-        jax.lax.fori_loop(0, seg, clear, 0)
-
-        def body(p, cnt):
-            word = ((p // n_win) << bits) | (p % n_win)
-            out = []
-            for ph in range(K2):  # static unroll: the K2 interlaced queues
-                fired = occ_ref[0, ph * P + p] > 0
-                sl = cnt[ph] - base
-
-                @pl.when(fired & (sl >= 0) & (sl < seg) & (cnt[ph] < depth))
-                def _append():
-                    buf_ref[slot(bs, ph, sl)] = word
-
-                out.append(cnt[ph] + fired.astype(jnp.int32))
-            return tuple(out)
-
-        cnt = jax.lax.fori_loop(0, n_pos, body,
-                                tuple(jnp.int32(0) for _ in range(K2)))
-        return tuple(jnp.minimum(c, depth) for c in cnt)
-
-    def drain(s, bs, totals):
-        """Accumulate segment ``s`` (resident in buf[bs]) into the charge map."""
-        base = s * seg
-
-        def dbody(d, _):
-            for ph in range(K2):  # static unroll: one event per phase, no
-                ky, kx = ph // K, ph % K  # write conflicts (interlacing)
-                word = buf_ref[slot(bs, ph, d)]
-                i_c = (word >> bits) & mask
-                j_c = word & mask
-                y = i_c * K + ky
-                x = j_c * K + kx
-
-                @pl.when((base + d < totals[ph]) & (i_c < n_win))
-                def _event():
-                    for dy in range(K):  # static unroll: kernel offsets
-                        for dx in range(K):
-                            ty = y - dy + pad
-                            tx = x - dx + pad
-
-                            @pl.when((ty >= 0) & (ty < H)
-                                     & (tx >= 0) & (tx < W))
-                            def _add():
-                                cur_ref[ty, pl.ds(tx, 1), :] += (
-                                    w_ref[dy, pl.ds(dx, 1), :])
-            return 0
-
-        jax.lax.fori_loop(0, seg, dbody, 0)
-
-    # software pipeline over double-buffered segments: compact s+1 while s
-    # drains (on hardware the fill is the encoder writing ahead of the
-    # accumulator; sequentialized here, the structure is what lowers)
-    totals = fill(0, 0)
-
-    def sbody(s, _):
-        bs = jax.lax.rem(s, 2)
-
-        @pl.when(s + 1 < n_seg(totals))
-        def _prefetch():
-            fill(s + 1, jax.lax.rem(s + 1, 2))
-
-        drain(s, bs, totals)
-        return 0
-
-    jax.lax.fori_loop(0, n_seg(totals), sbody, 0)
-
-
-def _kernel(occ_ref, w_ref, cur_ref, buf_ref, *,
-            K, n_win, bits, depth, seg, H, W, invalid):
-    """One grid step: all events of one (sample*step, channel) queue set."""
-    P = n_win * n_win
-    n_seg = -(-min(depth, P) // seg)  # ceil; slots beyond P never fill
-
-    @pl.when(pl.program_id(1) == 0)
+    @pl.when(c == 0)
     def _init():
-        cur_ref[...] = jnp.zeros_like(cur_ref)
+        mem_ref[...] = jnp.zeros_like(mem_ref)
 
-    _pipeline(occ_ref, w_ref, cur_ref, buf_ref, n_pos=P,
-              n_seg=lambda totals: n_seg, K=K, n_win=n_win, bits=bits,
-              depth=depth, seg=seg, H=H, W=W, invalid=invalid)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (n_win, wx, C_in), 2)
+    for ph in range(K2):  # static unroll: the K2 interlaced queues
+        ky, kx = divmod(ph, K)
 
+        @pl.when(cnt_ref[0, c * K2 + ph] > 0)
+        def _phase(ph=ph, ky=ky, kx=kx):
+            col = jnp.max(jnp.where(lane == c, spk_ref[ph], 0.0), axis=-1,
+                          keepdims=True)
+            spikes = jnp.broadcast_to(col, (n_win, wx, C_out))
+            for dx in range(K):  # static unroll: kernel offsets
+                sx, rx = divmod(kx - dx + pad, K)
+                row = pltpu.roll(spikes, sx % wx, 1) if sx else spikes
+                for dy in range(K):
+                    sy, ry = divmod(ky - dy + pad, K)
+                    mem_ref[ry, rx, 1 + sy:1 + sy + n_win] += (
+                        row * w_ref[dy, pl.ds(dx, 1), :])
 
-def kernel_specs(N, C_in, K, P, H, W, C_out, seg):
-    """BlockSpecs and scratch shared by both Pallas realizations.
-
-    The wrapper passes the occupancy as (N, C_in, 1, K2*P) and the weights
-    as (C_in, K, K, C_out) so that every block's two minor dimensions are
-    the array's own.
-    """
-    K2 = K * K
-    in_specs = [
-        pl.BlockSpec((None, None, 1, K2 * P), lambda n, c: (n, c, 0, 0),
-                     memory_space=pltpu.SMEM),
-        pl.BlockSpec((None, K, K, C_out), lambda n, c: (c, 0, 0, 0)),
-    ]
-    out_spec = pl.BlockSpec((None, H, W, C_out), lambda n, c: (n, 0, 0, 0))
-    scratch = [pltpu.SMEM((2 * K2 * seg,), jnp.int32)]
-    return in_specs, out_spec, scratch
-
-
-def kernel_operands(occ, weights):
-    """(N, C_in, K2, P) occupancy and HWIO weights in the kernels' layout."""
-    N, C_in, K2, P = occ.shape
-    return (occ.reshape(N, C_in, 1, K2 * P),
-            jnp.transpose(weights, (2, 0, 1, 3)))
+    @pl.when(c == C_in - 1)
+    def _deinterlace():
+        for ry in range(K):
+            for rx in range(K):
+                ny, nx = -(-(H - ry) // K), -(-(W - rx) // K)
+                out_ref[pl.ds(ry, ny, stride=K), pl.ds(rx, nx, stride=K),
+                        :] = mem_ref[ry, rx, 1:1 + ny, pl.ds(1, nx), :]
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "K", "n_win", "bits", "depth", "seg", "H", "W", "invalid", "interpret"))
+    "K", "n_win", "depth", "H", "W", "interpret"))
 def fused_spike_accum_pallas(
     occ: jnp.ndarray,      # (N, C_in, K2, P) int32 occupancy
     weights: jnp.ndarray,  # (K, K, C_in, C_out)
     *,
     K: int,
     n_win: int,
-    bits: int,
     depth: int,
     H: int,
     W: int,
-    invalid: int,
-    seg: int | None = None,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """Fused compact+accumulate, grid (N, C_in) with N = batch * time."""
+    """Fused drop rule + accumulate, grid (N, C_in) with N = batch * time.
+
+    The drop rule (``aeq.segment_keep``) is a prefix count over each
+    (channel, phase) queue, statically elided when ``depth >= P``; the
+    surviving events reach the kernel as 0/1 spike maps in its layout,
+    with their counts.
+    """
     N, C_in, K2, P = occ.shape
     C_out = weights.shape[-1]
-    seg = _default_seg(depth, n_win) if seg is None else min(seg, depth)
-    in_specs, out_spec, scratch = kernel_specs(N, C_in, K, P, H, W, C_out,
-                                               seg)
+    wx = _win_cols(n_win)
+    fired = segment_keep(occ, depth)
+    counts = fired.sum(-1, dtype=jnp.int32).reshape(N, 1, C_in * K2)
+    # (N, C, K2, wy, wx) -> (N, K2, wy, wx, C), one halo column before wx
+    spikes = fired.reshape(N, C_in, K2, n_win, n_win).transpose(0, 2, 3, 4, 1)
+    spikes = jnp.pad(spikes.astype(weights.dtype),
+                     ((0, 0), (0, 0), (0, 0), (1, wx - n_win - 1), (0, 0)))
     return pl.pallas_call(
-        functools.partial(_kernel, K=K, n_win=n_win, bits=bits, depth=depth,
-                          seg=seg, H=H, W=W, invalid=invalid),
+        functools.partial(_kernel, K=K, n_win=n_win, H=H, W=W),
         grid=(N, C_in),
-        in_specs=in_specs,
-        out_specs=out_spec,
+        in_specs=[
+            pl.BlockSpec((None, 1, C_in * K2), lambda n, c: (n, 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((None, K2, n_win, wx, C_in),
+                         lambda n, c: (n, 0, 0, 0, 0)),
+            pl.BlockSpec((None, K, K, C_out), lambda n, c: (c, 0, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((None, H, W, C_out),
+                               lambda n, c: (n, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((N, H, W, C_out), weights.dtype),
-        scratch_shapes=scratch,
+        scratch_shapes=[pltpu.VMEM((K, K, n_win + 2, wx, C_out),
+                                   weights.dtype)],
         interpret=interpret,
-    )(*kernel_operands(occ, weights))
+    )(counts, spikes, jnp.transpose(weights, (2, 0, 1, 3)))
 
 
 # ---------------------------------------------------------------------------

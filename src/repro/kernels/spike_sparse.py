@@ -1,10 +1,11 @@
 """Occupancy-gated sparse spike pipeline: work proportional to events.
 
 The fused pipeline (``spike_pipeline.py``) made the event path *compiled*,
-but not *sparse*: every realization does dense work per ``(sample*step,
-channel)`` grid cell regardless of queue occupancy, so measured latency is
-flat in spike rate — the very thing the paper's event-driven argument says
-should not happen. This module is the sparse realization:
+but not *sparse*: its realizations do the same work per ``(sample*step,
+channel)`` grid cell whatever the queue occupancy, short of skipping an
+empty phase, so latency is nearly flat in spike rate — the very thing the
+paper's event-driven argument says should not happen. This module is the
+sparse realization:
 
 1. **Event-list accumulation** (:func:`fused_spike_accum_sparse`): apply the
    AEQ drop rule, compact the surviving events into a static-capacity event
@@ -19,8 +20,9 @@ should not happen. This module is the sparse realization:
    spike rate.
 
 2. **Occupancy-gated Pallas kernel** (:func:`fused_spike_accum_sparse_pallas`):
-   the double-buffered segment walk of ``spike_pipeline._kernel``, with
-   per-cell ``pl.when`` early-exit on empty ``(row, channel)`` cells,
+   a double-buffered walk of fixed-depth AE queue segments held as SMEM
+   scalars (one event and one C_out-wide row at a time), with per-cell
+   ``pl.when`` early-exit on empty ``(row, channel)`` cells,
    occupancy-bounded fill/drain loops (traced ``fori_loop`` bounds instead
    of static worst-case ones), and a ragged dispatch path that compacts the
    ``(N, …)`` grid to only-active rows via the same prefix-sum index map
@@ -47,8 +49,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..audit.contracts import KernelContract, QuantContract
 from ..core.quantization import quantize_symmetric
-from .spike_pipeline import (_default_seg, _pipeline, kernel_operands,
-                             kernel_specs)
 
 # Declared resource/dtype intent, verified by ``python -m repro.audit``
 # (see docs/CONTRACTS.md): with ``weight_bits`` the accumulate is int8
@@ -63,8 +63,9 @@ CONTRACT = KernelContract(name="fused_spike_accum_sparse",
 def vmem_blocks(*, K, H, W, C_out, **_unused):
     """Per-grid-cell VMEM buffers of the gated Pallas kernel, as data.
 
-    The dense-walk pipeline's blocks; the gate pair (cell total + fill
-    bound) sits in SMEM beside the occupancy block. See ``audit.vmem``.
+    The weight slice and the membrane map; the occupancy block, the queue
+    segments and the gate pair (cell total + fill bound) sit in SMEM. See
+    ``audit.vmem``.
     """
     return [
         ("w_block", (K, K, C_out), 4, True),
@@ -203,12 +204,128 @@ def fused_spike_accum_sparse(
 # Occupancy-gated Pallas kernel (per-cell early exit + ragged row dispatch)
 # ---------------------------------------------------------------------------
 
+# The scalar event walk: the AE queues are filled and drained one SMEM
+# scalar at a time, bounded by the gate's traced per-cell counts.
+#
+# Mosaic layout of one grid cell:
+#
+# - the (K2, P) occupancy block sits in SMEM, flattened to (1, K2*P) so its
+#   two minor dimensions are full: the encoder reads it one scalar at a time
+#   (a vector walk over positions would be a load at a dynamic lane offset);
+# - the AE queue segments are SMEM scratch, written and read as scalars;
+# - the (K, K, C_out) weight slice and the (H, W, C_out) membrane map are
+#   VMEM blocks whose two minor dimensions are full, and each event updates
+#   one C_out-wide row of the map.
+
+def _pipeline(occ_ref, w_ref, cur_ref, buf_ref, *, n_pos, n_seg, K, n_win,
+              bits, depth, seg, H, W, invalid):
+    """Compact + accumulate one (sample*step, channel) cell.
+
+    ``n_pos`` bounds the encoder's position walk and ``n_seg(totals)`` the
+    segment loop; both are traced scalars derived from the cell's gate.
+    """
+    K2 = K * K
+    P = n_win * n_win
+    pad = K // 2
+    mask = (1 << bits) - 1
+
+    def slot(bs, ph, i):
+        return (bs * K2 + ph) * seg + i
+
+    def fill(s, bs):
+        """Compact events with queue slot in [s*seg, (s+1)*seg) into buf[bs].
+
+        Sequential append with a running per-phase count — the hardware
+        queue write pointer, exactly as in kernels/spike_compact.py, but
+        the destination is on-chip scratch instead of an HBM output.
+        Returns the per-phase totals capped at ``depth`` (identical to
+        AEQ.counts for this segment set): the drain-side liveness bound.
+        """
+        base = s * seg
+
+        def clear(i, _):
+            for ph in range(K2):
+                buf_ref[slot(bs, ph, i)] = jnp.int32(invalid)
+            return 0
+
+        jax.lax.fori_loop(0, seg, clear, 0)
+
+        def body(p, cnt):
+            word = ((p // n_win) << bits) | (p % n_win)
+            out = []
+            for ph in range(K2):  # static unroll: the K2 interlaced queues
+                fired = occ_ref[0, ph * P + p] > 0
+                sl = cnt[ph] - base
+
+                @pl.when(fired & (sl >= 0) & (sl < seg) & (cnt[ph] < depth))
+                def _append():
+                    buf_ref[slot(bs, ph, sl)] = word
+
+                out.append(cnt[ph] + fired.astype(jnp.int32))
+            return tuple(out)
+
+        cnt = jax.lax.fori_loop(0, n_pos, body,
+                                tuple(jnp.int32(0) for _ in range(K2)))
+        return tuple(jnp.minimum(c, depth) for c in cnt)
+
+    def drain(s, bs, totals):
+        """Accumulate segment ``s`` (resident in buf[bs]) into the charge map."""
+        base = s * seg
+
+        def dbody(d, _):
+            for ph in range(K2):  # static unroll: one event per phase, no
+                ky, kx = ph // K, ph % K  # write conflicts (interlacing)
+                word = buf_ref[slot(bs, ph, d)]
+                i_c = (word >> bits) & mask
+                j_c = word & mask
+                y = i_c * K + ky
+                x = j_c * K + kx
+
+                @pl.when((base + d < totals[ph]) & (i_c < n_win))
+                def _event():
+                    for dy in range(K):  # static unroll: kernel offsets
+                        for dx in range(K):
+                            ty = y - dy + pad
+                            tx = x - dx + pad
+
+                            @pl.when((ty >= 0) & (ty < H)
+                                     & (tx >= 0) & (tx < W))
+                            def _add():
+                                cur_ref[ty, pl.ds(tx, 1), :] += (
+                                    w_ref[dy, pl.ds(dx, 1), :])
+            return 0
+
+        jax.lax.fori_loop(0, seg, dbody, 0)
+
+    # software pipeline over double-buffered segments: compact s+1 while s
+    # drains (on hardware the fill is the encoder writing ahead of the
+    # accumulator; sequentialized here, the structure is what lowers)
+    totals = fill(0, 0)
+
+    def sbody(s, _):
+        bs = jax.lax.rem(s, 2)
+
+        @pl.when(s + 1 < n_seg(totals))
+        def _prefetch():
+            fill(s + 1, jax.lax.rem(s + 1, 2))
+
+        drain(s, bs, totals)
+        return 0
+
+    jax.lax.fori_loop(0, n_seg(totals), sbody, 0)
+
+
+def _default_seg(depth: int, n_win: int) -> int:
+    """Segment length: <= depth, <= the max events a phase can hold (P)."""
+    return max(1, min(64, depth, n_win * n_win))
+
+
 def _sparse_kernel(occ_ref, w_ref, gate_ref, cur_ref, buf_ref, *,
                    K, n_win, bits, depth, seg, H, W, invalid):
-    """``spike_pipeline._kernel`` with occupancy gates.
+    """The scalar segment walk of :func:`_pipeline` under occupancy gates.
 
     ``gate_ref`` is this cell's SMEM pair (total events, 1 + last active
-    position). Differences from the dense-walk kernel:
+    position). Against an ungated walk over every position and segment:
 
     - the whole fill/drain pipeline sits under ``pl.when(cell_total > 0)``,
       so an empty ``(row, channel)`` grid cell costs only the accumulator
@@ -302,21 +419,26 @@ def fused_spike_accum_sparse_pallas(
     ).astype(jnp.int32)                                    # (N_run, C_in)
     gates = jnp.stack([cell_tot, cell_pmax], -1)[:, :, None]
 
-    in_specs, out_spec, scratch = kernel_specs(N_run, C_in, K, P, H, W,
-                                               C_out, seg)
-    in_specs.append(pl.BlockSpec((None, None, 1, 2),
-                                 lambda n, c: (n, c, 0, 0),
-                                 memory_space=pltpu.SMEM))
+    # the occupancy as (N, C_in, 1, K2*P) and the weights as (C_in, K, K,
+    # C_out), so that every block's two minor dimensions are the array's own
     out = pl.pallas_call(
         functools.partial(_sparse_kernel, K=K, n_win=n_win, bits=bits,
                           depth=depth, seg=seg, H=H, W=W, invalid=invalid),
         grid=(N_run, C_in),
-        in_specs=in_specs,
-        out_specs=out_spec,
+        in_specs=[
+            pl.BlockSpec((None, None, 1, K2 * P), lambda n, c: (n, c, 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((None, K, K, C_out), lambda n, c: (c, 0, 0, 0)),
+            pl.BlockSpec((None, None, 1, 2), lambda n, c: (n, c, 0, 0),
+                         memory_space=pltpu.SMEM),
+        ],
+        out_specs=pl.BlockSpec((None, H, W, C_out),
+                               lambda n, c: (n, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((N_run, H, W, C_out), weights.dtype),
-        scratch_shapes=scratch,
+        scratch_shapes=[pltpu.SMEM((2 * K2 * seg,), jnp.int32)],
         interpret=interpret,
-    )(*kernel_operands(occ, weights), gates)
+    )(occ.reshape(N_run, C_in, 1, K2 * P),
+      jnp.transpose(weights, (2, 0, 1, 3)), gates)
 
     if row_order is not None:
         # scatter the active-row results back into the full (N, …) output;

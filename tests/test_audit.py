@@ -344,7 +344,7 @@ def test_dead_module_fires_on_orphan(tmp_path):
 def test_vmem_overflow_detected_at_absurd_geometry():
     huge = vmem.kernel_footprint(
         "repro.kernels.spike_pipeline",
-        K=3, n_win=342, depth=256, H=1024, W=1024, C_out=1024)
+        K=3, n_win=342, depth=256, H=1024, W=1024, C_in=1024, C_out=1024)
     assert huge > VMEM_BUDGET_BYTES
 
 

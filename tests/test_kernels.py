@@ -99,20 +99,26 @@ def test_fused_spike_accum_xla_matches_ref(hw, c_in, c_out, depth):
                                atol=1e-4, rtol=1e-4)
 
 
-@pytest.mark.parametrize("hw,c_in,c_out,depth,seg", [
-    (6, 1, 4, 16, None), (9, 2, 8, 4, 2), (10, 1, 8, 3, 2),
+@pytest.mark.parametrize("hw,c_in,c_out,depth,p_fire", [
+    (6, 1, 4, 16, 0.25), (9, 2, 8, 4, 0.25), (10, 1, 8, 3, 0.25),
+    # the cifar10 event geometries (n_win 11, 4, 1) at their C_out
+    (32, 2, 32, 256, 0.25), (10, 2, 64, 256, 0.25), (3, 2, 128, 256, 0.5),
+    # queues that overflow at n_win 11 (P = 121)
+    (32, 2, 32, 3, 0.25), (32, 2, 32, 64, 0.9),
+    # every phase skipped; every window fired
+    (32, 2, 32, 256, 0.0), (10, 2, 64, 256, 1.0),
 ])
 def test_fused_spike_accum_pallas_interp_matches_ref(hw, c_in, c_out,
-                                                     depth, seg):
-    """The Pallas kernel body (interpret mode): double-buffered segment walk
-    accumulates exactly the surviving events, for seg | depth and not."""
-    fmt, occ = _occupancy(hw, c_in, 2, seed=hw + depth)
+                                                     depth, p_fire):
+    """The Pallas kernel body (interpret mode): the vector drain over the
+    interlaced banks accumulates exactly the surviving events, with queues
+    that overflow, phases that are empty and windows that all fire."""
+    fmt, occ = _occupancy(hw, c_in, 2, seed=hw + depth, p_fire=p_fire)
     rng = np.random.default_rng(2)
     w = jnp.asarray(rng.normal(size=(3, 3, c_in, c_out)), jnp.float32)
     kw = dict(K=3, n_win=fmt.n_win, bits=fmt.bits_coord, depth=depth,
               H=hw, W=hw, invalid=fmt.invalid_word)
-    out_p = ops.fused_spike_accum(occ, w, impl="pallas_interpret", seg=seg,
-                                  **kw)
+    out_p = ops.fused_spike_accum(occ, w, impl="pallas_interpret", **kw)
     out_r = ops.fused_spike_accum(occ, w, impl="ref", **kw)
     np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_r),
                                atol=1e-4, rtol=1e-4)

@@ -75,9 +75,8 @@ def _compile_fused(kernel, cp, depth, sharding, **extra):
     w = _spec((cp.kernel, cp.kernel, cp.in_c, cp.out_c), jnp.float32,
               sharding)
     fn = functools.partial(
-        kernel, K=cp.kernel, n_win=cp.fmt.n_win, bits=cp.fmt.bits_coord,
-        depth=depth, H=cp.in_hw, W=cp.in_hw, invalid=cp.fmt.invalid_word,
-        interpret=False, **extra)
+        kernel, K=cp.kernel, n_win=cp.fmt.n_win, depth=depth, H=cp.in_hw,
+        W=cp.in_hw, interpret=False, **extra)
     compiled = jax.jit(fn).lower(occ, w).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
@@ -92,8 +91,9 @@ def test_fused_spike_accum_pallas_compiles(one_chip, cp, depth):
 @pytest.mark.parametrize("cp", GEOMETRIES, ids=_geom_id)
 def test_fused_spike_accum_sparse_pallas_compiles(one_chip, cp, depth):
     # the ragged row grid and the int8 accumulate at the largest depth
-    extra = (dict(n_rows=SERVE_BATCH * T // 2, weight_bits=8)
-             if depth == max(DEPTHS) else {})
+    extra = dict(bits=cp.fmt.bits_coord, invalid=cp.fmt.invalid_word)
+    if depth == max(DEPTHS):
+        extra.update(n_rows=SERVE_BATCH * T // 2, weight_bits=8)
     _compile_fused(fused_spike_accum_sparse_pallas, cp, depth, one_chip,
                    **extra)
 
