@@ -3,10 +3,11 @@
 Each Pallas kernel module declares its per-grid-cell resident buffers via a
 ``vmem_blocks(**geometry)`` helper next to its ``CONTRACT`` — the same
 shapes its BlockSpecs/scratch_shapes construct, as data. The estimator
-evaluates those at every geometry the paper's model zoo can launch (each
-conv stage of each ``PAPER_SPECS`` spec, at the study's default queue
-depth) and flags any kernel whose resident bytes — pipelined blocks
-counted twice for double-buffering — exceed the per-core VMEM budget.
+evaluates those at every geometry the model zoo can launch (each conv
+stage of each ``PAPER_SPECS`` spec and of ``VGG16``, at the study's
+default queue depth) and flags any kernel whose resident bytes —
+pipelined blocks counted twice for double-buffering — exceed the per-core
+VMEM budget.
 
 This is a *static* gate: it catches a BlockSpec edit that would OOM on TPU
 without needing a TPU (Mosaic would only report it at compile time, and CI
@@ -48,7 +49,7 @@ def paper_geometries(depth: int = DEFAULT_DEPTH):
     from .. import configs
     from ..core import engine
 
-    for dataset, d in configs.PAPER_SPECS.items():
+    for dataset, d in {**configs.PAPER_SPECS, "vgg16": configs.VGG16}.items():
         plan = engine.compile_plan(d["spec"], d["hw"], d["c"])
         for cp in plan.convs:
             yield dataset, dict(K=cp.kernel, n_win=cp.fmt.n_win,

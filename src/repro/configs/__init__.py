@@ -18,6 +18,20 @@ PAPER_SPECS = {
                     hw=32, c=3, params=446122),
 }
 
+# VGG-16 on CIFAR-10, the deep net of the ANN-to-SNN conversion literature
+# (Sengupta et al., arXiv:1802.02627): the 13 conv widths of Simonyan and
+# Zisserman's configuration D (arXiv:1409.1556, Table 1) at a 32x32x3
+# input, with the single 512->10 classifier of the CIFAR-10 VGG16 in
+# github.com/kuangliu/pytorch-cifar (models/vgg.py). Batch norm folds into
+# the conv weights and biases on conversion. After four 2x2 pools its last
+# three 3x3 convs run on a 2x2 map: kernels wider than their map.
+VGG16 = dict(
+    spec="64C3-64C3-P2-128C3-128C3-P2-256C3-256C3-256C3-P2-512C3-512C3-"
+         "512C3-P2-512C3-512C3-512C3-P2-10",
+    hw=32, c=3, params=14719818,
+    sources=("arXiv:1409.1556 Table 1 column D", "arXiv:1802.02627",
+             "github.com/kuangliu/pytorch-cifar models/vgg.py"))
+
 SHAPES = {
     "train_4k": dict(kind="train", seq=4096, batch=256),
     "prefill_32k": dict(kind="prefill", seq=32768, batch=32),

@@ -208,6 +208,10 @@ def compile_plan(
 
     The result is a pure-static NamedTuple (ints and formats only), so it is
     hashable and safely shared across jit traces, backends, and modules.
+    A conv's kernel may be wider than its map (VGG-16's 2x2 stages): SAME
+    padding makes it the zero-padded convolution, and the map is one
+    window of the AE format (``n_win = 1``). A pool window wider than its
+    map is a :class:`SpecError`.
     """
     layers = parse_spec(spec)
     if layers[-1][0] != "dense":
@@ -223,10 +227,6 @@ def compile_plan(
         ly = layers[li]
         # parse_spec guarantees only conv (+ directly-following pool) here
         cout, k = ly[1], ly[2]
-        if k > hw:
-            raise SpecError(
-                f"spec {spec!r} layer {li}: kernel {k} exceeds the "
-                f"{hw}x{hw} feature map")
         pool = 0
         if li + 1 < len(layers) - 1 and layers[li + 1][0] == "pool":
             pool = layers[li + 1][1]
@@ -1111,6 +1111,21 @@ def _first_call(key) -> bool:
     return True
 
 
+def plan_size(cfg: SNNConfig, B: int) -> dict:
+    """The static size of the plan a call of ``B`` samples compiles.
+
+    ``stages``: its conv stages; ``event_pairs``: the (sample x time step,
+    input channel) pairs its spike-fed conv stages take in, sum of
+    ``B * T * in_c`` (the first stage is fed the image as a current unless
+    the input is spike-encoded). Attributes of the compile spans, so a
+    traced set-up puts compile time down to the plan that cost it.
+    """
+    plan = compile_plan(cfg.spec, cfg.input_hw, cfg.input_c, cfg.compressed)
+    fed = plan.convs if cfg.input_mode == "binary" else plan.convs[1:]
+    return {"stages": len(plan.convs),
+            "event_pairs": B * cfg.T * sum(cp.in_c for cp in fed)}
+
+
 def infer(params, thresholds, cfg: SNNConfig, image, *,
           backend: str = "dense"):
     """Run one (H, W, C) sample; returns ``(logits, SNNStats)``."""
@@ -1118,7 +1133,7 @@ def infer(params, thresholds, cfg: SNNConfig, image, *,
     if _first_call((cfg, backend, None)):
         obs.counter("engine.jit_miss")
         with obs.span("engine.jit_compile", backend=backend, B=0,
-                      spec=cfg.spec):
+                      spec=cfg.spec, **plan_size(cfg, 1)):
             return run(params, tuple(thresholds), image)
     obs.counter("engine.jit_hit")
     return run(params, tuple(thresholds), image)
@@ -1162,7 +1177,7 @@ def infer_batch(params, thresholds, cfg: SNNConfig, images, *,
     if _first_call((cfg, backend, B)):
         obs.counter("engine.jit_miss")
         with obs.span("engine.jit_compile", backend=backend, B=B,
-                      spec=cfg.spec):
+                      spec=cfg.spec, **plan_size(cfg, B)):
             return run(params, tuple(thresholds), images)
     obs.counter("engine.jit_hit")
     return run(params, tuple(thresholds), images)

@@ -79,17 +79,37 @@ def vmem_blocks(*, K, n_win, H, W, C_in, C_out, **_unused):
     per-phase event counts live in SMEM (:func:`fused_spike_accum_pallas`).
     """
     wx = _win_cols(n_win)
+    tile, J = _lane_tiles(C_out)
     return [
         ("spike_block", (K * K, n_win, wx, C_in), 4, True),
-        ("w_block", (K, K, C_out), 4, True),
-        ("out_block", (H, W, C_out), 4, True),
-        ("membrane", (K, K, n_win + 2, wx, C_out), 4, False),
+        ("w_block", (K, K, J * tile), 4, True),
+        ("out_block", _tiled(J, (H, W, tile)), 4, True),
+        ("membrane", _tiled(J, (K, K, n_win + 2, wx, tile)), 4, False),
     ]
 
 
 def _win_cols(n_win: int) -> int:
     """Window columns of a bank with its one-window halo, to whole sublanes."""
     return -(-(n_win + 2) // 8) * 8
+
+
+#: lanes of a vector register: the widest C_out one membrane bank holds
+LANES = 128
+
+
+def _lane_tiles(C_out: int) -> tuple[int, int]:
+    """(lanes a tile, tiles): C_out up to 128 is one tile of its own
+    width; a wider C_out is split into 128-lane tiles (zero-padded up to
+    a whole number of them)."""
+    if C_out <= LANES:
+        return C_out, 1
+    return LANES, -(-C_out // LANES)
+
+
+def _tiled(J: int, shape: tuple) -> tuple:
+    """A buffer's shape with the lane-tile axis in front, when there are
+    several tiles."""
+    return shape if J == 1 else (J,) + shape
 
 
 # ---------------------------------------------------------------------------
@@ -102,16 +122,26 @@ def _win_cols(n_win: int) -> int:
 #   C_in): window row leading, window column on sublanes (one zero column
 #   of halo before it), channels on lanes. The block index ignores c, so
 #   it is fetched once per row; channel c is picked by a lane compare and
-#   a lane reduction, and broadcast back across the C_out lanes;
+#   a lane reduction, and broadcast back across a tile's lanes;
 # - the membrane is a VMEM scratch in interlaced layout (K, K, n_win + 2,
 #   wx, C_out): bank (ry, rx) holds pixels (wy * K + ry, wx * K + rx) at
 #   [wy + 1, wx + 1], so every (phase, offset) update is one slab of whole
 #   tiles, shifted by at most one window (a leading index for rows, a
 #   sublane roll for columns) and cut by the halo;
+# - a C_out wider than 128 lanes is split into J tiles of 128: membrane
+#   and output block gain a leading tile axis, so each strided store of
+#   the de-interlace sees a 128-lane last dim, and every slab update is
+#   made once per tile, with that tile's lanes of the weights;
 # - the per-(channel, phase) event counts sit in SMEM, one row per n;
 # - the (K, K, C_out) weight slice is a VMEM block, one row per offset.
+#
+# A map narrower than the kernel (H < K) has one window (n_win = 1): only
+# its phases ky < H, kx < W hold events, only its banks ry < H, rx < W are
+# read, and only updates that stay in the window (no shift) reach them, so
+# the rest is left out statically.
 
-def _kernel(cnt_ref, spk_ref, w_ref, out_ref, mem_ref, *, K, n_win, H, W):
+def _kernel(cnt_ref, spk_ref, w_ref, out_ref, mem_ref, *, K, n_win, H, W,
+            tile, J):
     """Accumulate channel c of row n; de-interlace after the last channel.
 
     Each phase's events lie K apart, so for one kernel offset no two of
@@ -123,7 +153,13 @@ def _kernel(cnt_ref, spk_ref, w_ref, out_ref, mem_ref, *, K, n_win, H, W):
     K2 = K * K
     pad = K // 2
     _, _, wx, C_in = spk_ref.shape
-    C_out = mem_ref.shape[-1]
+    narrow = H < K
+
+    def at(j, *idx):  # index into tile j of the membrane or the output
+        return idx if J == 1 else (j,) + idx
+
+    def lanes(j):
+        return slice(j * tile, (j + 1) * tile)
 
     @pl.when(c == 0)
     def _init():
@@ -132,27 +168,39 @@ def _kernel(cnt_ref, spk_ref, w_ref, out_ref, mem_ref, *, K, n_win, H, W):
     lane = jax.lax.broadcasted_iota(jnp.int32, (n_win, wx, C_in), 2)
     for ph in range(K2):  # static unroll: the K2 interlaced queues
         ky, kx = divmod(ph, K)
+        if narrow and (ky >= H or kx >= W):
+            continue  # no pixel of the map lies in this phase
 
         @pl.when(cnt_ref[0, c * K2 + ph] > 0)
         def _phase(ph=ph, ky=ky, kx=kx):
             col = jnp.max(jnp.where(lane == c, spk_ref[ph], 0.0), axis=-1,
                           keepdims=True)
-            spikes = jnp.broadcast_to(col, (n_win, wx, C_out))
+            spikes = jnp.broadcast_to(col, (n_win, wx, tile))
             for dx in range(K):  # static unroll: kernel offsets
                 sx, rx = divmod(kx - dx + pad, K)
+                if narrow and (sx or rx >= W):
+                    continue
                 row = pltpu.roll(spikes, sx % wx, 1) if sx else spikes
                 for dy in range(K):
                     sy, ry = divmod(ky - dy + pad, K)
-                    mem_ref[ry, rx, 1 + sy:1 + sy + n_win] += (
-                        row * w_ref[dy, pl.ds(dx, 1), :])
+                    if narrow and (sy or ry >= H):
+                        continue
+                    for j in range(J):  # static unroll: lane tiles
+                        mem_ref[at(j, ry, rx, slice(1 + sy, 1 + sy + n_win))
+                                ] += row * w_ref[dy, pl.ds(dx, 1), lanes(j)]
 
     @pl.when(c == C_in - 1)
     def _deinterlace():
         for ry in range(K):
             for rx in range(K):
                 ny, nx = -(-(H - ry) // K), -(-(W - rx) // K)
-                out_ref[pl.ds(ry, ny, stride=K), pl.ds(rx, nx, stride=K),
-                        :] = mem_ref[ry, rx, 1:1 + ny, pl.ds(1, nx), :]
+                if ny <= 0 or nx <= 0:
+                    continue  # a bank that holds no pixel of the map
+                for j in range(J):
+                    out_ref[at(j, pl.ds(ry, ny, stride=K),
+                               pl.ds(rx, nx, stride=K), slice(None))] = (
+                        mem_ref[at(j, ry, rx, slice(1, 1 + ny),
+                                   pl.ds(1, nx), slice(None))])
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -178,29 +226,40 @@ def fused_spike_accum_pallas(
     N, C_in, K2, P = occ.shape
     C_out = weights.shape[-1]
     wx = _win_cols(n_win)
+    tile, J = _lane_tiles(C_out)
     fired = segment_keep(occ, depth)
     counts = fired.sum(-1, dtype=jnp.int32).reshape(N, 1, C_in * K2)
     # (N, C, K2, wy, wx) -> (N, K2, wy, wx, C), one halo column before wx
     spikes = fired.reshape(N, C_in, K2, n_win, n_win).transpose(0, 2, 3, 4, 1)
     spikes = jnp.pad(spikes.astype(weights.dtype),
                      ((0, 0), (0, 0), (0, 0), (1, wx - n_win - 1), (0, 0)))
-    return pl.pallas_call(
-        functools.partial(_kernel, K=K, n_win=n_win, H=H, W=W),
+    weights = jnp.transpose(weights, (2, 0, 1, 3))
+    if J * tile != C_out:
+        weights = jnp.pad(weights, ((0, 0),) * 3 + ((0, J * tile - C_out),))
+    out = pl.pallas_call(
+        functools.partial(_kernel, K=K, n_win=n_win, H=H, W=W, tile=tile,
+                          J=J),
         grid=(N, C_in),
         in_specs=[
             pl.BlockSpec((None, 1, C_in * K2), lambda n, c: (n, 0, 0),
                          memory_space=pltpu.SMEM),
             pl.BlockSpec((None, K2, n_win, wx, C_in),
                          lambda n, c: (n, 0, 0, 0, 0)),
-            pl.BlockSpec((None, K, K, C_out), lambda n, c: (c, 0, 0, 0)),
+            pl.BlockSpec((None, K, K, J * tile), lambda n, c: (c, 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((None, H, W, C_out),
-                               lambda n, c: (n, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((N, H, W, C_out), weights.dtype),
-        scratch_shapes=[pltpu.VMEM((K, K, n_win + 2, wx, C_out),
+        out_specs=pl.BlockSpec((None,) + _tiled(J, (H, W, tile)),
+                               lambda n, c: (n,) + (0,) * (3 + (J > 1))),
+        out_shape=jax.ShapeDtypeStruct((N,) + _tiled(J, (H, W, tile)),
+                                       weights.dtype),
+        scratch_shapes=[pltpu.VMEM(_tiled(J, (K, K, n_win + 2, wx, tile)),
                                    weights.dtype)],
         interpret=interpret,
-    )(counts, spikes, jnp.transpose(weights, (2, 0, 1, 3)))
+    )(counts, spikes, weights)
+    if J == 1:
+        return out
+    # (N, J, H, W, 128) -> (N, H, W, C_out)
+    out = out.transpose(0, 2, 3, 1, 4).reshape(N, H, W, J * tile)
+    return out[..., :C_out]
 
 
 # ---------------------------------------------------------------------------

@@ -127,7 +127,8 @@ class ModelHandle:
         obs.counter("serve.plan_compile")
         with obs.span("serve.aot_compile", model=self.name,
                       backend=self.backend, bucket=bucket,
-                      sharded=self._bucket_sharded(bucket)):
+                      sharded=self._bucket_sharded(bucket),
+                      **engine.plan_size(self.cfg, bucket)):
             if self._bucket_sharded(bucket):
                 from .. import parallel
 

@@ -34,7 +34,7 @@ def test_original_encoding_word_width():
 
 
 @given(
-    width=st.integers(6, 64),
+    width=st.integers(1, 64),  # a map at most the kernel is one window
     kernel=st.sampled_from([3, 5]),
     seed=st.integers(0, 2**16),
 )
@@ -51,6 +51,18 @@ def test_pack_unpack_roundtrip(width, kernel, seed):
     np.testing.assert_array_equal(np.asarray(v2), valid)
     np.testing.assert_array_equal(np.asarray(i2)[valid], i[valid])
     np.testing.assert_array_equal(np.asarray(j2)[valid], j[valid])
+
+
+@pytest.mark.parametrize("width, kernel, n_win", [(1, 3, 1), (2, 3, 1),
+                                                  (3, 5, 1), (4, 3, 2)])
+def test_maps_at_most_the_kernel_are_one_window(width, kernel, n_win):
+    """VGG-16's 2x2 maps under 3x3 kernels: one window, whose word packs."""
+    fmt = encoding.make_format(width, kernel)
+    assert fmt.n_win == n_win
+    i, j, valid = encoding.unpack_events(fmt, encoding.pack_events(
+        fmt, jnp.arange(n_win), jnp.zeros(n_win, jnp.int32),
+        jnp.ones(n_win, bool)))
+    assert list(np.asarray(i)) == list(range(n_win)) and bool(valid.all())
 
 
 @given(width=st.integers(4, 96), kernel=st.sampled_from([2, 3, 5, 7]))

@@ -294,7 +294,7 @@ def test_parse_spec_accepts_paper_specs():
 @pytest.mark.parametrize("bad, hw, fragment", [
     ("32C3", 28, "end with a dense"),
     ("32C3-P2-32C3", 28, "end with a dense"),
-    ("2C5-4", 3, "kernel 5 exceeds"),
+    ("2C5-P4-4", 3, "pool window 4 exceeds"),  # a kernel may exceed it
     ("2C3-P9-4", 6, "pool window 9 exceeds"),
 ])
 def test_compile_plan_rejects(bad, hw, fragment):

@@ -107,6 +107,10 @@ def test_fused_spike_accum_xla_matches_ref(hw, c_in, c_out, depth):
     (32, 2, 32, 3, 0.25), (32, 2, 32, 64, 0.9),
     # every phase skipped; every window fired
     (32, 2, 32, 256, 0.0), (10, 2, 64, 256, 1.0),
+    # C_out in 128-lane tiles (VGG-16's 8x8 and 4x4 stages; one padded);
+    # maps narrower than the kernel (VGG-16's 2x2, and 1x1)
+    (8, 2, 256, 256, 0.5), (4, 2, 512, 256, 0.5), (4, 2, 160, 256, 0.5),
+    (2, 2, 512, 256, 0.5), (1, 2, 8, 256, 1.0),
 ])
 def test_fused_spike_accum_pallas_interp_matches_ref(hw, c_in, c_out,
                                                      depth, p_fire):

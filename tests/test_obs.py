@@ -94,6 +94,29 @@ def test_span_records_survive_exceptions():
     assert s.name == "doomed" and s.dur == 1.0
 
 
+def test_compile_spans_carry_the_plan_size():
+    """``engine.jit_compile`` and ``serve.aot_compile`` name the plan's conv
+    stages and its spike-fed (sample x step, input channel) pairs."""
+    obs.enable(clock=FakeClock())
+    params = snn_model.init_params(jax.random.PRNGKey(7), SPEC, HW, C)
+    th = [jnp.asarray(0.5)] * N_LAYERS
+    # a config no other test compiles, so this call is its first
+    cfg = snn_model.SNNConfig(spec=SPEC, input_hw=HW, input_c=C, T=5,
+                              depth=13, mode="mttfs_cont")
+    engine.infer_batch(params, th, cfg, jnp.zeros((3, HW, HW, C)))
+    registry = ModelRegistry()
+    registry.register("toy", params, th, cfg, backend="queue_pallas")
+    registry.get("toy").plan_for(4)
+    spans = {s.name: s.attrs for s in obs.spans()}
+    # 6C3 takes the image as a current; 4C3 takes 6 channels of spikes
+    assert spans["engine.jit_compile"]["stages"] == 2
+    assert spans["engine.jit_compile"]["event_pairs"] == 3 * 5 * 6
+    assert spans["serve.aot_compile"]["event_pairs"] == 4 * 5 * 6
+    binary = cfg._replace(input_mode="binary")
+    assert engine.plan_size(binary, 1) == {"stages": 2,
+                                           "event_pairs": 5 * (1 + 6)}
+
+
 def test_events_and_metrics_record_when_enabled():
     obs.enable(clock=FakeClock())
     obs.event("cache.evict", key="k")

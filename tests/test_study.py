@@ -207,8 +207,8 @@ def test_unknown_dataset_named_error():
 def test_spec_validation_bad_net_is_spec_error():
     with pytest.raises(SpecError):  # even kernel — engine grammar error
         StudySpec(dataset="mnist", net="6C4-8", input_hw=28, input_c=1)
-    with pytest.raises(SpecError):  # kernel exceeds feature map
-        StudySpec(dataset="mnist", net="6C31-8", input_hw=28, input_c=1)
+    with pytest.raises(SpecError):  # pool window exceeds feature map
+        StudySpec(dataset="mnist", net="6C31-P29-8", input_hw=28, input_c=1)
 
 
 def test_spec_defaults_resolve_from_paper_zoo():

@@ -4,8 +4,9 @@ Interpret mode runs a kernel body on the CPU but never asks Mosaic to
 lower it, so tiling, SMEM/VMEM and scalar-extraction refusals only show
 when the kernel is compiled for the chip. These tests compile each kernel
 for one described (not attached) v5e device at every event-driven conv
-geometry of ``configs.PAPER_SPECS``, at the serving batch (64 images x
-T=4 steps) and the serving and default queue depths. Nothing runs.
+geometry of ``configs.PAPER_SPECS`` (the fused kernel at ``configs.VGG16``'s
+too), at the serving batch (64 images x T=4 steps) and the serving and
+default queue depths. Nothing runs.
 
 The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process may load the TPU library, and every
@@ -29,18 +30,20 @@ SERVE_BATCH, T = 64, 4
 DEPTHS = (64, 256)  # serve/bench.py serving depth, SNNConfig default
 
 
-def _event_geometries():
+def _event_geometries(specs):
     """Distinct conv stages fed by spike events: every conv after the
     analog-input first layer, one ConvPlan per (in_hw, in_c, out_c)."""
     seen = {}
-    for d in configs.PAPER_SPECS.values():
+    for d in specs:
         plan = engine.compile_plan(d["spec"], d["hw"], d["c"])
         for cp in plan.convs[1:]:
             seen.setdefault((cp.in_hw, cp.in_c, cp.out_c), cp)
     return [seen[k] for k in sorted(seen)]
 
 
-GEOMETRIES = _event_geometries()
+GEOMETRIES = _event_geometries(configs.PAPER_SPECS.values())
+#: VGG-16's eight: C_out up to 512 (four lane tiles), maps down to 2x2
+VGG16_GEOMETRIES = _event_geometries([configs.VGG16])
 
 
 def _geom_id(cp):
@@ -82,7 +85,7 @@ def _compile_fused(kernel, cp, depth, sharding, **extra):
 
 
 @pytest.mark.parametrize("depth", DEPTHS)
-@pytest.mark.parametrize("cp", GEOMETRIES, ids=_geom_id)
+@pytest.mark.parametrize("cp", GEOMETRIES + VGG16_GEOMETRIES, ids=_geom_id)
 def test_fused_spike_accum_pallas_compiles(one_chip, cp, depth):
     _compile_fused(fused_spike_accum_pallas, cp, depth, one_chip)
 
