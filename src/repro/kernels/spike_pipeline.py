@@ -7,24 +7,31 @@ words) and ``event_accum`` (queue words -> membrane charge). DeepFire2
 wins from *not* doing that — the event stream stays on-chip between the
 encoder and the accumulator. This module is that fusion:
 
-    per-phase occupancy ──drop rule──▶ surviving spike maps ──drain──▶ charge
+    per-phase occupancy ──drop rule──▶ surviving spike maps ──MXU──▶ charge
                                         (VMEM, one block per row)
 
 Design points:
 
-- **Batch axis in the kernel grid.** The grid is ``(N, C_in)`` where
+- **Batch axis in the kernel grid.** The grid is ``(J, N)`` where
   ``N = B * T`` — every (sample, time-step) segment is an independent grid
-  row. Queue backends previously reached batch > 1 only through an outer
-  ``jax.vmap`` of the whole single-sample program.
-- **A vector drain over the interlaced banks.** The K² phase queues of a
-  channel are the paper's memory interlacing: the events of one phase lie
-  K apart, so at one kernel offset no two of them touch the same membrane
-  pixel. The kernel keeps the membrane interlaced, one bank per phase, and
-  adds each (phase, offset) pair's events as one masked vector update of
-  ``spikes ⊗ w[dy, dx, :]`` over the whole window grid: K⁴ slab updates
-  per (row, channel) cell, none per event. A phase whose queue is empty is
-  skipped; that skip is the event-driven part of the work. The membrane
-  is de-interlaced once, after the last input channel.
+  row — and ``J`` is the number of 128-lane tiles of C_out. The tile is the
+  outer axis, so a tile's weights stay in VMEM while every row runs.
+- **The channel sum on the MXU.** The K² phase queues of a channel are the
+  paper's memory interlacing: the events of one phase lie K apart, so at
+  one kernel offset no two of them touch the same membrane pixel. A grid
+  cell holds one row's surviving 0/1 spike maps of every phase with the
+  input channels on lanes, and for each kernel offset (dy, dx) contracts
+  them with ``w[dy, dx]`` in one matmul: the charge of every phase at that
+  offset, summed over all input channels. Each phase's slice of it is then
+  one conflict-free slab update of an interlaced membrane, one bank per
+  phase: K⁴ slab updates per grid cell, none per channel or event. An
+  empty queue costs zero lanes of the matmul. The membrane is
+  de-interlaced at the end of the cell.
+- **f32 from bf16 parts.** The spikes are exactly 0 or 1, and the weights
+  reach the kernel as three bf16 parts whose sum is the f32 weight, laid
+  side by side on the contraction axis: every MXU product is exact and the
+  MXU accumulates in f32, so the contraction is as exact as the f32 conv
+  (Mosaic's default precision rounds f32 operands to one bf16 part).
 - **Same drop semantics as ``core.aeq.compact_spikes``.** Events beyond
   ``depth`` per (channel, phase) queue are dropped in window-row-major
   order (a prefix count, statically elided when ``depth >= P``), so the
@@ -75,16 +82,17 @@ def vmem_blocks(*, K, n_win, H, W, C_in, C_out, **_unused):
 
     Mirrors :func:`fused_spike_accum_pallas`'s VMEM BlockSpecs and scratch
     exactly — ``(name, block shape, bytes per element, double-buffered)``;
-    pipelined in/out blocks are double-buffered by the Mosaic emitter. The
-    per-phase event counts live in SMEM (:func:`fused_spike_accum_pallas`).
+    pipelined in/out blocks are double-buffered by the Mosaic emitter.
     """
     wx = _win_cols(n_win)
-    tile, J = _lane_tiles(C_out)
+    tile, _ = _lane_tiles(C_out)
+    depth_c = _contraction(C_in)
     return [
-        ("spike_block", (K * K, n_win, wx, C_in), 4, True),
-        ("w_block", (K, K, J * tile), 4, True),
-        ("out_block", _tiled(J, (H, W, tile)), 4, True),
-        ("membrane", _tiled(J, (K, K, n_win + 2, wx, tile)), 4, False),
+        ("spike_block", (_live_phases(K, H, W) * n_win * wx, depth_c), 2,
+         True),
+        ("w_block", (K, K, depth_c, tile), 2, True),
+        ("out_block", (H, W, tile), 4, True),
+        ("membrane", (K, K, n_win + 2, wx, tile), 4, False),
     ]
 
 
@@ -93,8 +101,12 @@ def _win_cols(n_win: int) -> int:
     return -(-(n_win + 2) // 8) * 8
 
 
-#: lanes of a vector register: the widest C_out one membrane bank holds
+#: lanes of a vector register: the widest C_out one membrane bank holds,
+#: and the MXU's contraction width
 LANES = 128
+
+#: bf16 parts of an f32 weight: 3 x 8 significand bits hold its 24
+W_PARTS = 3
 
 
 def _lane_tiles(C_out: int) -> tuple[int, int]:
@@ -106,101 +118,103 @@ def _lane_tiles(C_out: int) -> tuple[int, int]:
     return LANES, -(-C_out // LANES)
 
 
-def _tiled(J: int, shape: tuple) -> tuple:
-    """A buffer's shape with the lane-tile axis in front, when there are
-    several tiles."""
-    return shape if J == 1 else (J,) + shape
+def _contraction(C_in: int) -> int:
+    """Contraction width: a copy of the channels per weight part,
+    zero-padded to whole MXU passes."""
+    return -(-W_PARTS * C_in // LANES) * LANES
+
+
+def _live_phases(K: int, H: int, W: int) -> int:
+    """Phases the spike block holds: all K², or on a map narrower than the
+    kernel the prefix up to its last live phase (ky < H, kx < W)."""
+    return K * K if H >= K else (H - 1) * K + W
+
+
+def _bf16_parts(weights: jnp.ndarray) -> jnp.ndarray:
+    """(..., C_in, C_out) f32 -> (..., 3 C_in, C_out) bf16 whose three C_in
+    blocks sum to the weights exactly: each part is the top 16 bits of what
+    the parts before it left.
+
+    The cut is made on the bits, not by a round trip through bf16: XLA may
+    keep an f32 -> bf16 -> f32 round trip in f32 (excess precision), which
+    would leave the second and third parts zero.
+    """
+    parts, rest = [], weights.astype(jnp.float32)
+    for _ in range(W_PARTS):
+        bits = jax.lax.bitcast_convert_type(rest, jnp.uint32)
+        part = jax.lax.bitcast_convert_type(bits & jnp.uint32(0xFFFF0000),
+                                            jnp.float32)
+        parts.append(part.astype(jnp.bfloat16))
+        rest = rest - part
+    return jnp.concatenate(parts, axis=-2)
 
 
 # ---------------------------------------------------------------------------
 # Pallas TPU kernel
 # ---------------------------------------------------------------------------
 #
-# Mosaic layout of one grid cell (row n, input channel c):
+# Mosaic layout of one grid cell (lane tile j, row n):
 #
-# - the spike maps of the K2 phases sit in one VMEM block (K2, n_win, wx,
-#   C_in): window row leading, window column on sublanes (one zero column
-#   of halo before it), channels on lanes. The block index ignores c, so
-#   it is fetched once per row; channel c is picked by a lane compare and
-#   a lane reduction, and broadcast back across a tile's lanes;
+# - the spike block (phases * n_win * wx, contraction) holds the row's
+#   surviving 0/1 spike maps in bf16: phase, window row and window column
+#   (one zero column of halo before it, to whole sublanes) on sublanes,
+#   and the input channels on lanes, once per weight part, zero-padded to
+#   whole 128-lane passes. Its block index ignores j, and J is small;
+# - the weight block (K, K, contraction, tile) holds tile j's lanes of the
+#   three bf16 weight parts (stacked on the contraction axis, matching the
+#   spike copies). Its block index ignores n, so it is fetched once per
+#   tile, not once per row;
+# - per kernel offset, one matmul of the spike block with that offset's
+#   weights gives every phase's charge at the offset, summed over the
+#   input channels and the weight parts in f32;
 # - the membrane is a VMEM scratch in interlaced layout (K, K, n_win + 2,
-#   wx, C_out): bank (ry, rx) holds pixels (wy * K + ry, wx * K + rx) at
-#   [wy + 1, wx + 1], so every (phase, offset) update is one slab of whole
-#   tiles, shifted by at most one window (a leading index for rows, a
-#   sublane roll for columns) and cut by the halo;
-# - a C_out wider than 128 lanes is split into J tiles of 128: membrane
-#   and output block gain a leading tile axis, so each strided store of
-#   the de-interlace sees a 128-lane last dim, and every slab update is
-#   made once per tile, with that tile's lanes of the weights;
-# - the per-(channel, phase) event counts sit in SMEM, one row per n;
-# - the (K, K, C_out) weight slice is a VMEM block, one row per offset.
+#   wx, tile): bank (ry, rx) holds pixels (wy * K + ry, wx * K + rx) at
+#   [wy + 1, wx + 1], so each phase's slice of an offset's charge is one
+#   slab of whole tiles, shifted by at most one window (a leading index for
+#   rows, a sublane roll for columns) and cut by the halo;
+# - a C_out wider than 128 lanes is split into J tiles of 128 on the grid:
+#   each strided store of the de-interlace sees a 128-lane last dim, and
+#   the output block is tile j's lanes of the (N, H, W, C_out) output.
 #
 # A map narrower than the kernel (H < K) has one window (n_win = 1): only
-# its phases ky < H, kx < W hold events, only its banks ry < H, rx < W are
-# read, and only updates that stay in the window (no shift) reach them, so
-# the rest is left out statically.
+# its phases ky < H, kx < W hold events (the spike block stops at the last
+# of them), only its banks ry < H, rx < W are read, and only updates that
+# stay in the window (no shift) reach them, so the rest is left out
+# statically.
 
-def _kernel(cnt_ref, spk_ref, w_ref, out_ref, mem_ref, *, K, n_win, H, W,
-            tile, J):
-    """Accumulate channel c of row n; de-interlace after the last channel.
-
-    Each phase's events lie K apart, so for one kernel offset no two of
-    them reach the same membrane pixel: the phase's whole contribution at
-    that offset is one conflict-free masked vector update. A phase with no
-    event is skipped.
-    """
-    c = pl.program_id(1)
-    K2 = K * K
+def _kernel(spk_ref, w_ref, out_ref, mem_ref, *, K, n_win, H, W):
+    """Contract row n's spike maps with tile j's weights, offset by offset;
+    add each phase's share to its bank, then de-interlace."""
     pad = K // 2
-    _, _, wx, C_in = spk_ref.shape
+    wx, tile = mem_ref.shape[-2:]
+    win = n_win * wx  # spike-block rows a phase
     narrow = H < K
 
-    def at(j, *idx):  # index into tile j of the membrane or the output
-        return idx if J == 1 else (j,) + idx
-
-    def lanes(j):
-        return slice(j * tile, (j + 1) * tile)
-
-    @pl.when(c == 0)
-    def _init():
-        mem_ref[...] = jnp.zeros_like(mem_ref)
-
-    lane = jax.lax.broadcasted_iota(jnp.int32, (n_win, wx, C_in), 2)
-    for ph in range(K2):  # static unroll: the K2 interlaced queues
-        ky, kx = divmod(ph, K)
-        if narrow and (ky >= H or kx >= W):
-            continue  # no pixel of the map lies in this phase
-
-        @pl.when(cnt_ref[0, c * K2 + ph] > 0)
-        def _phase(ph=ph, ky=ky, kx=kx):
-            col = jnp.max(jnp.where(lane == c, spk_ref[ph], 0.0), axis=-1,
-                          keepdims=True)
-            spikes = jnp.broadcast_to(col, (n_win, wx, tile))
-            for dx in range(K):  # static unroll: kernel offsets
+    mem_ref[...] = jnp.zeros_like(mem_ref)
+    spikes = spk_ref[...]
+    for dy in range(K):  # static unroll: kernel offsets
+        for dx in range(K):
+            charge = jnp.dot(spikes, w_ref[dy, dx],
+                             preferred_element_type=jnp.float32)
+            for ph in range(spk_ref.shape[0] // win):  # the live phases
+                ky, kx = divmod(ph, K)
+                sy, ry = divmod(ky - dy + pad, K)
                 sx, rx = divmod(kx - dx + pad, K)
-                if narrow and (sx or rx >= W):
-                    continue
-                row = pltpu.roll(spikes, sx % wx, 1) if sx else spikes
-                for dy in range(K):
-                    sy, ry = divmod(ky - dy + pad, K)
-                    if narrow and (sy or ry >= H):
-                        continue
-                    for j in range(J):  # static unroll: lane tiles
-                        mem_ref[at(j, ry, rx, slice(1 + sy, 1 + sy + n_win))
-                                ] += row * w_ref[dy, pl.ds(dx, 1), lanes(j)]
+                if narrow and (kx >= W or sy or sx or ry >= H or rx >= W):
+                    continue  # reaches no pixel of the map
+                part = charge[ph * win:(ph + 1) * win].reshape(n_win, wx,
+                                                               tile)
+                if sx:
+                    part = pltpu.roll(part, sx % wx, 1)
+                mem_ref[ry, rx, 1 + sy:1 + sy + n_win] += part
 
-    @pl.when(c == C_in - 1)
-    def _deinterlace():
-        for ry in range(K):
-            for rx in range(K):
-                ny, nx = -(-(H - ry) // K), -(-(W - rx) // K)
-                if ny <= 0 or nx <= 0:
-                    continue  # a bank that holds no pixel of the map
-                for j in range(J):
-                    out_ref[at(j, pl.ds(ry, ny, stride=K),
-                               pl.ds(rx, nx, stride=K), slice(None))] = (
-                        mem_ref[at(j, ry, rx, slice(1, 1 + ny),
-                                   pl.ds(1, nx), slice(None))])
+    for ry in range(K):
+        for rx in range(K):
+            ny, nx = -(-(H - ry) // K), -(-(W - rx) // K)
+            if ny <= 0 or nx <= 0:
+                continue  # a bank that holds no pixel of the map
+            out_ref[pl.ds(ry, ny, stride=K), pl.ds(rx, nx, stride=K), :] = (
+                mem_ref[ry, rx, 1:1 + ny, pl.ds(1, nx), :])
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -216,50 +230,43 @@ def fused_spike_accum_pallas(
     W: int,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """Fused drop rule + accumulate, grid (N, C_in) with N = batch * time.
+    """Fused drop rule + accumulate, grid (J, N) with N = batch * time.
 
     The drop rule (``aeq.segment_keep``) is a prefix count over each
     (channel, phase) queue, statically elided when ``depth >= P``; the
-    surviving events reach the kernel as 0/1 spike maps in its layout,
-    with their counts.
+    surviving events reach the kernel as 0/1 spike maps in its layout.
     """
     N, C_in, K2, P = occ.shape
     C_out = weights.shape[-1]
     wx = _win_cols(n_win)
     tile, J = _lane_tiles(C_out)
-    fired = segment_keep(occ, depth)
-    counts = fired.sum(-1, dtype=jnp.int32).reshape(N, 1, C_in * K2)
-    # (N, C, K2, wy, wx) -> (N, K2, wy, wx, C), one halo column before wx
-    spikes = fired.reshape(N, C_in, K2, n_win, n_win).transpose(0, 2, 3, 4, 1)
-    spikes = jnp.pad(spikes.astype(weights.dtype),
-                     ((0, 0), (0, 0), (0, 0), (1, wx - n_win - 1), (0, 0)))
-    weights = jnp.transpose(weights, (2, 0, 1, 3))
-    if J * tile != C_out:
-        weights = jnp.pad(weights, ((0, 0),) * 3 + ((0, J * tile - C_out),))
+    n_ph = _live_phases(K, H, W)
+    depth_c = _contraction(C_in)
+    fired = segment_keep(occ, depth)[:, :, :n_ph]
+    # (N, C, ph, wy, wx) -> (N, ph, wy, wx, C), one halo column before wx
+    spikes = fired.reshape(N, C_in, n_ph, n_win, n_win).transpose(
+        0, 2, 3, 4, 1)
+    spikes = jnp.concatenate([spikes.astype(jnp.bfloat16)] * W_PARTS, -1)
+    spikes = jnp.pad(spikes, ((0, 0),) * 3 + ((1, wx - n_win - 1),
+                                              (0, depth_c - W_PARTS * C_in)))
+    spikes = spikes.reshape(N, n_ph * n_win * wx, depth_c)
+    w = jnp.pad(_bf16_parts(weights),
+                ((0, 0), (0, 0), (0, depth_c - W_PARTS * C_in),
+                 (0, J * tile - C_out)))
     out = pl.pallas_call(
-        functools.partial(_kernel, K=K, n_win=n_win, H=H, W=W, tile=tile,
-                          J=J),
-        grid=(N, C_in),
+        functools.partial(_kernel, K=K, n_win=n_win, H=H, W=W),
+        grid=(J, N),
         in_specs=[
-            pl.BlockSpec((None, 1, C_in * K2), lambda n, c: (n, 0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((None, K2, n_win, wx, C_in),
-                         lambda n, c: (n, 0, 0, 0, 0)),
-            pl.BlockSpec((None, K, K, J * tile), lambda n, c: (c, 0, 0, 0)),
+            pl.BlockSpec((None,) + spikes.shape[1:], lambda j, n: (n, 0, 0)),
+            pl.BlockSpec((K, K, depth_c, tile), lambda j, n: (0, 0, 0, j)),
         ],
-        out_specs=pl.BlockSpec((None,) + _tiled(J, (H, W, tile)),
-                               lambda n, c: (n,) + (0,) * (3 + (J > 1))),
-        out_shape=jax.ShapeDtypeStruct((N,) + _tiled(J, (H, W, tile)),
-                                       weights.dtype),
-        scratch_shapes=[pltpu.VMEM(_tiled(J, (K, K, n_win + 2, wx, tile)),
-                                   weights.dtype)],
+        out_specs=pl.BlockSpec((None, H, W, tile), lambda j, n: (n, 0, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((N, H, W, J * tile), weights.dtype),
+        scratch_shapes=[pltpu.VMEM((K, K, n_win + 2, wx, tile),
+                                   jnp.float32)],
         interpret=interpret,
-    )(counts, spikes, weights)
-    if J == 1:
-        return out
-    # (N, J, H, W, 128) -> (N, H, W, C_out)
-    out = out.transpose(0, 2, 3, 1, 4).reshape(N, H, W, J * tile)
-    return out[..., :C_out]
+    )(spikes, w)
+    return out if J * tile == C_out else out[..., :C_out]
 
 
 # ---------------------------------------------------------------------------

@@ -1,11 +1,15 @@
 """Per-kernel shape/dtype sweeps: Pallas (interpret=True) vs ref.py oracle."""
+import functools
+
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import aeq, encoding
 from repro.kernels import ops, ref
+from repro.kernels.spike_pipeline import fused_spike_accum_pallas
 
 
 @pytest.mark.parametrize("hw,c_in,c_out,depth", [
@@ -111,12 +115,19 @@ def test_fused_spike_accum_xla_matches_ref(hw, c_in, c_out, depth):
     # maps narrower than the kernel (VGG-16's 2x2, and 1x1)
     (8, 2, 256, 256, 0.5), (4, 2, 512, 256, 0.5), (4, 2, 160, 256, 0.5),
     (2, 2, 512, 256, 0.5), (1, 2, 8, 256, 1.0),
+    # input channels as wide as the MXU contraction and wider: cifar10's
+    # full 3x3 128->128 and 32x32 32->32, VGG-16's 4x4 256->512 and 2x2
+    # 512->512; a C_in that is no multiple of 8; overflow at C_in 128
+    (3, 128, 128, 256, 0.5), (32, 32, 32, 256, 0.4),
+    (4, 256, 512, 256, 0.5), (2, 512, 512, 256, 0.5),
+    (10, 5, 64, 256, 0.5), (10, 128, 64, 3, 0.5),
 ])
 def test_fused_spike_accum_pallas_interp_matches_ref(hw, c_in, c_out,
                                                      depth, p_fire):
-    """The Pallas kernel body (interpret mode): the vector drain over the
-    interlaced banks accumulates exactly the surviving events, with queues
-    that overflow, phases that are empty and windows that all fire."""
+    """The Pallas kernel body (interpret mode): the per-offset contraction
+    over the input channels and the slab updates of the interlaced banks
+    accumulate exactly the surviving events, with queues that overflow,
+    phases that are empty and windows that all fire."""
     fmt, occ = _occupancy(hw, c_in, 2, seed=hw + depth, p_fire=p_fire)
     rng = np.random.default_rng(2)
     w = jnp.asarray(rng.normal(size=(3, 3, c_in, c_out)), jnp.float32)
@@ -126,6 +137,85 @@ def test_fused_spike_accum_pallas_interp_matches_ref(hw, c_in, c_out,
     out_r = ops.fused_spike_accum(occ, w, impl="ref", **kw)
     np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_r),
                                atol=1e-4, rtol=1e-4)
+
+
+def _run_to_pallas(fn, *args):
+    """Run ``fn``'s program eagerly up to its ``pallas_call``: yields each
+    equation with its operand values, the ``pallas_call`` last (not run)."""
+    (call,) = jax.make_jaxpr(fn)(*args).jaxpr.eqns  # the entry point's jit
+    body = call.params["jaxpr"]
+    env = dict(zip(body.jaxpr.invars, args))
+    env.update(zip(body.jaxpr.constvars, body.consts))
+
+    def read(v):
+        return v.val if isinstance(v, jax.extend.core.Literal) else env[v]
+
+    for eqn in body.jaxpr.eqns:
+        ins = [read(v) for v in eqn.invars]
+        yield eqn, ins
+        if eqn.primitive.name == "pallas_call":
+            return
+        outs = eqn.primitive.bind(*ins, **eqn.params)
+        env.update(zip(eqn.outvars,
+                       outs if eqn.primitive.multiple_results else [outs]))
+    raise AssertionError("no pallas_call traced")
+
+
+def _dots(jaxpr):
+    """Every dot_general of a jaxpr, nested."""
+    found = [e for e in jaxpr.eqns if e.primitive.name == "dot_general"]
+    for eqn in jaxpr.eqns:
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _dots(sub)
+    return found
+
+
+@pytest.mark.parametrize("hw,c_in,c_out", [
+    (10, 5, 64), (4, 256, 512), (2, 512, 512),
+])
+def test_fused_spike_accum_pallas_contracts_at_f32(hw, c_in, c_out):
+    """Every contraction in the kernel body is as exact as f32: it asks for
+    HIGHEST, or multiplies 0/1 spikes in bf16 by bf16 weight parts into
+    f32, and the parts that reach the body sum to the f32 weights.
+
+    On a TPU, Mosaic's default precision rounds an f32 operand to one bf16
+    part; the CPU (and interpret mode) computes f32 whatever the
+    precision, so only the program shows it.
+    """
+    fmt, occ = _occupancy(hw, c_in, 2, seed=hw, p_fire=0.5)
+    w = jnp.asarray(np.random.default_rng(3).normal(size=(3, 3, c_in, c_out)),
+                    jnp.float32)
+    fn = functools.partial(fused_spike_accum_pallas, K=3, n_win=fmt.n_win,
+                           depth=256, H=hw, W=hw, interpret=True)
+    for call, ins in _run_to_pallas(fn, occ, w):
+        if (call.primitive.name == "convert_element_type"
+                and call.params["new_dtype"] == jnp.bfloat16
+                and ins[0].dtype == jnp.float32):
+            # exact, so no rounding is left that XLA's excess precision
+            # could skip
+            low = np.asarray(ins[0]).view(np.uint32) & 0xFFFF
+            assert not low.any(), "an f32 -> bf16 rounding"
+    spikes, parts = ins
+    dots = _dots(call.params["jaxpr"])
+    assert dots, "no contraction in the kernel body"
+    highest = jax.lax.Precision.HIGHEST
+    for d in dots:
+        exact_parts = (all(v.aval.dtype == jnp.bfloat16 for v in d.invars)
+                       and d.params["preferred_element_type"] == jnp.float32)
+        assert exact_parts or d.params["precision"] == (highest, highest), d
+    # one copy of the 0/1 spikes per weight part, then zero padding
+    spikes = np.asarray(spikes, np.float32)
+    assert set(np.unique(spikes)) <= {0.0, 1.0}
+    copies = np.split(spikes[..., :3 * c_in], 3, axis=-1)
+    for copy in copies[1:]:
+        np.testing.assert_array_equal(copy, copies[0])
+    assert not spikes[..., 3 * c_in:].any()
+    # three bf16 parts on the contraction axis that sum to the weights
+    assert parts.dtype == jnp.bfloat16
+    split = np.asarray(parts, np.float32)[:, :, :3 * c_in, :c_out]
+    np.testing.assert_array_equal(
+        split.reshape(3, 3, 3, c_in, c_out).sum(2), np.asarray(w))
+    assert not np.asarray(parts, np.float32)[:, :, 3 * c_in:].any()
 
 
 def test_fused_spike_accum_default_is_compiled():
