@@ -72,16 +72,6 @@ def quant_matmul_bench():
          f"tput_gmacs={macs / us_ref / 1e3:.2f}")
 
 
-def moe_gather_bench():
-    rng = np.random.default_rng(3)
-    x = jnp.asarray(rng.normal(size=(1024, 128)), jnp.float32)
-    idx = jnp.asarray(rng.integers(-1, 1024, 512), jnp.int32)
-    us = timed(lambda: ops.moe_gather(x, idx, backend="ref"))
-    emit("kernel/moe_gather_ref", us,
-         f"slots=512;routing_word_bytes=4;"
-         f"struct_bytes_saved={512 * 12}")
-
-
 def snn_engine_scan_bench():
     """Engine dense backend (one T-batched conv per layer + ``lax.scan``
     time loop) vs the seed implementation's unrolled per-step Python loop
@@ -261,5 +251,5 @@ def snn_sparse_rate_sweep_bench():
 
 
 ALL = [event_accum_bench, spike_compact_bench, quant_matmul_bench,
-       moe_gather_bench, snn_engine_scan_bench, snn_engine_queue_bench,
+       snn_engine_scan_bench, snn_engine_queue_bench,
        snn_sparse_rate_sweep_bench]

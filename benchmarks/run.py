@@ -1,11 +1,11 @@
 """Benchmark entry point — one function per paper table/figure.
 
-    PYTHONPATH=src python -m benchmarks.run [--only tableX|figY|kernel|roofline]
+    PYTHONPATH=src python -m benchmarks.run [--only tableX|figY|kernel]
                                             [--json BENCH_YYYYMMDD.json]
 
 Prints ``name,us_per_call,derived`` CSV rows. Timing columns are CPU wall
 times (interpret-mode for Pallas kernels); `derived` carries the model
-metrics (energy, FPS/W, roofline terms) that constitute the reproduction.
+metrics (energy, FPS/W) that constitute the reproduction.
 
 ``--json OUT`` additionally writes a machine-readable perf snapshot
 (name -> us_per_call + parsed derived metrics) so the perf trajectory
@@ -15,7 +15,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 import platform
 import sys
 import time
@@ -65,51 +64,23 @@ def write_snapshot(path: str, failures: int) -> None:
     print(f"# wrote {len(snap['rows'])} rows to {path}", file=sys.stderr)
 
 
-def _run_dryrun(multi_pod: bool) -> None:
-    """Generate the roofline dry-run artifacts in a subprocess.
-
-    A subprocess because launch/dryrun.py must set
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before jax
-    initializes — doing that in-process would poison every other suite. It
-    runs before this process touches jax, and on the CPU backend only, so
-    it never contends for an accelerator.
-    """
-    import subprocess
-
-    cmd = [sys.executable, "-m", "repro.launch.dryrun", "--all"]
-    if multi_pod:
-        cmd.append("--multi-pod")
-    print(f"# --with-dryrun: {' '.join(cmd)}", file=sys.stderr)
-    subprocess.run(cmd, check=True, env=dict(os.environ, JAX_PLATFORMS="cpu"))
-
-
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="",
                     help="substring filter on benchmark function names")
     ap.add_argument("--json", default="", metavar="OUT",
                     help="write a BENCH_*.json perf snapshot to OUT")
-    ap.add_argument("--with-dryrun", action="store_true",
-                    help="first run launch/dryrun.py (subprocess) so the "
-                         "roofline/* rows have artifacts to read; without "
-                         "it, missing roofline rows are dropped with a "
-                         "logged reason")
-    ap.add_argument("--multi-pod", action="store_true",
-                    help="with --with-dryrun: also compile the 2x16x16 mesh")
     args = ap.parse_args()
-
-    if args.with_dryrun:
-        _run_dryrun(args.multi_pod)
 
     # benchmarks.coldstart_bench is not a suite here: it spawns worker
     # processes that need the accelerator, which this process holds once a
     # suite has run — run it from its own command line
     from . import break_even, distributions, kernel_bench, memory_study, \
-        paper_tables, parallel_bench, roofline_report, serve_bench
+        paper_tables, parallel_bench, serve_bench
 
     suites = (paper_tables.ALL + distributions.ALL + memory_study.ALL +
               kernel_bench.ALL + break_even.ALL + serve_bench.ALL +
-              parallel_bench.ALL + roofline_report.ALL)
+              parallel_bench.ALL)
 
     print("name,us_per_call,derived")
     failures = 0

@@ -3,7 +3,7 @@
 Each Pallas kernel module declares its per-grid-cell resident buffers via a
 ``vmem_blocks(**geometry)`` helper next to its ``CONTRACT`` — the same
 shapes its BlockSpecs/scratch_shapes construct, as data. The estimator
-evaluates those at every geometry the model zoo can launch (each conv
+evaluates those at every geometry the served nets launch (each conv
 stage of each ``PAPER_SPECS`` spec and of ``VGG16``, at the study's
 default queue depth) and flags any kernel whose resident bytes —
 pipelined blocks counted twice for double-buffering — exceed the per-core
@@ -45,7 +45,7 @@ def estimate_bytes(blocks) -> int:
 
 
 def paper_geometries(depth: int = DEFAULT_DEPTH):
-    """Every (dataset, ConvPlan-derived geometry) the zoo can launch."""
+    """Every (dataset, ConvPlan-derived geometry) the served nets launch."""
     from .. import configs
     from ..core import engine
 

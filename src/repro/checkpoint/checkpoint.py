@@ -7,15 +7,14 @@ Layout of a checkpoint directory:
         shard_00000.npz    # one file per host (sharded-by-host save)
     <root>/step_000123.COMMITTED   # atomic commit marker (rename-based)
 
-Guarantees engineered for 1000+-node runs:
+Guarantees:
 - **atomicity**: data is written to ``step_X.tmp-<nonce>`` and renamed; a
   checkpoint without its COMMITTED marker is ignored by ``latest_step`` and
   garbage-collected — a killed writer can never corrupt restore.
 - **integrity**: every shard carries a content hash in the manifest;
   ``restore`` verifies before use and falls back to the previous checkpoint.
-- **mesh-agnostic restore**: arrays are saved unsharded-logical (gathered per
-  host shard) with their logical axes recorded, so a job restarted on a
-  different device count / mesh re-shards on load (elastic restart).
+- **mesh-agnostic restore**: arrays are saved unsharded and restored as
+  host arrays, so a process on any device count or mesh can place them.
 - **async**: ``AsyncCheckpointer`` snapshots device arrays to host then
   writes on a background thread — the training loop never blocks on disk.
 """
@@ -172,12 +171,6 @@ def restore(root: str, template, *, step: int | None = None,
             last_err = e
             continue
     raise IOError(f"no restorable checkpoint under {root}: {last_err}")
-
-
-def reshard_on_load(tree, shardings):
-    """Place restored host arrays onto (a possibly different) mesh."""
-    return jax.tree.map(
-        lambda x, s: jax.device_put(x, s), tree, shardings)
 
 
 class AsyncCheckpointer:

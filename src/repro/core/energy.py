@@ -32,7 +32,7 @@ E_HBM_BYTE = 15.0       # HBM read/write per byte
 E_VMEM_BYTE = 0.8       # on-chip vector memory per byte
 E_REG_BYTE = 0.05       # register file per byte
 
-# --- TPU v5e machine constants (roofline section uses the same) -----------
+# --- TPU v5e machine constants -------------------------------------------
 PEAK_BF16_FLOPS = 197e12
 PEAK_INT8_OPS = 394e12
 HBM_BW = 819e9

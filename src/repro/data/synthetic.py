@@ -7,8 +7,6 @@ DESIGN.md "Data gate").
   generates the fewest spikes).
 - ``svhn``    (32x32x3): the same digits, colored, on textured backgrounds.
 - ``cifar``   (32x32x3): 10 procedural shape/texture classes.
-- ``tokens``  : synthetic LM token streams with n-gram structure (so a
-  language model has something learnable).
 
 Everything is generated with numpy from an integer seed — fully reproducible
 and shardable by slicing the sample index range.
@@ -114,33 +112,6 @@ def make_cifar_like(n: int, seed: int = 0, hw: int = 32):
         img += rng.normal(0, 0.1, (hw, hw, 3)).astype(np.float32)
         imgs[i] = np.clip(img, 0, 1)
     return imgs, labels.astype(np.int32)
-
-
-def make_tokens(n_tokens: int, vocab: int, seed: int = 0, order: int = 2):
-    """Markov token stream: learnable n-gram structure for LM training.
-
-    A fixed random transition structure maps the previous ``order`` tokens to
-    a peaked next-token distribution (top-8 candidates at 80% mass).
-    """
-    rng = np.random.default_rng(seed + 3)
-    ctx_hash_w = rng.integers(1, 2**31 - 1, size=order)
-    n_buckets = 4096
-    # Zipf-skewed candidate pool: the corpus has learnable *unigram*
-    # structure too, so even tiny smoke models show loss movement fast,
-    # while the bucket structure rewards real context modeling.
-    zipf_p = 1.0 / np.arange(1, vocab + 1)
-    zipf_p /= zipf_p.sum()
-    cand = rng.choice(vocab, size=(n_buckets, 8), p=zipf_p)
-
-    out = np.empty(n_tokens, np.int64)
-    out[:order] = rng.integers(0, vocab, size=order)
-    u = rng.random(n_tokens)
-    pick = rng.integers(0, 8, size=n_tokens)
-    noise = rng.choice(vocab, size=n_tokens, p=zipf_p)
-    for i in range(order, n_tokens):
-        h = int((out[i - order : i] * ctx_hash_w).sum() % n_buckets)
-        out[i] = cand[h, pick[i]] if u[i] < 0.8 else noise[i]
-    return out.astype(np.int32)
 
 
 DATASETS = {

@@ -16,7 +16,6 @@ import jax.numpy as jnp
 
 from . import ref as _ref
 from .event_accum import event_accum as _event_accum
-from .moe_gather import moe_gather as _moe_gather
 from .quant_matmul import quant_matmul as _quant_matmul
 from .spike_compact import spike_compact as _spike_compact
 from .spike_pipeline import (fused_spike_accum_pallas as _fused_pallas,
@@ -159,8 +158,3 @@ def quant_matmul(a_q, b_q, a_scale, b_scale, *, backend=None, **blocks):
     return _quant_matmul(a_q, b_q, a_scale, b_scale,
                          interpret=_interpret(), **blocks)
 
-
-def moe_gather(x, indices, *, backend="pallas", block_rows=8):
-    if backend == "ref":
-        return _ref.moe_gather_ref(x, indices)
-    return _moe_gather(x, indices, block_rows=block_rows, interpret=_interpret())

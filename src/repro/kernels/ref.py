@@ -163,9 +163,3 @@ def quant_matmul_ref(a_q, b_q, a_scale, b_scale):
     ).astype(jnp.float32)
     return prod * (a_scale * b_scale)
 
-
-def moe_gather_ref(x, indices):
-    """Oracle for kernels.moe_gather: plain row gather with -1 -> zeros."""
-    ok = indices >= 0
-    rows = x[jnp.clip(indices, 0, x.shape[0] - 1)]
-    return rows * ok[:, None].astype(x.dtype)

@@ -18,9 +18,11 @@ built on top (``python -m repro.study.sweep``).
 """
 from .executor import (batch_runner_sharded, infer_batch_sharded,  # noqa: F401
                        use_mesh)
-from .mesh import DATA_AXIS, data_mesh, device_count, mesh_size  # noqa: F401
+from .mesh import (DATA_AXIS, batch_partition_spec, data_mesh,  # noqa: F401
+                   device_count, mesh_size)
 
 __all__ = [
-    "DATA_AXIS", "data_mesh", "device_count", "mesh_size",
+    "DATA_AXIS", "batch_partition_spec", "data_mesh", "device_count",
+    "mesh_size",
     "batch_runner_sharded", "infer_batch_sharded", "use_mesh",
 ]

@@ -15,8 +15,8 @@ Batch sizes that do not divide the mesh reuse the serving layer's padding
 trick: the batch is zero-padded to the next multiple of the mesh size and
 the valid prefix sliced back out (``engine.slice_valid``) — exactly the
 ``infer_batch_masked`` contract applied at mesh granularity. Whether a
-shape needs the fallback is decided by the same divisibility rule the
-FSDP/TP resolver uses (:func:`repro.sharding.resolver.batch_partition_spec`).
+shape needs the fallback is decided by
+:func:`repro.parallel.mesh.batch_partition_spec`.
 
 :func:`use_mesh` installs the sharded path as the engine's batch dispatch,
 so everything built on ``engine.infer_batch`` — the study ``collect`` stage,
@@ -35,8 +35,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from .. import obs
 from ..core import engine
 from ..core.neuron import _on_registry_change
-from ..sharding.resolver import batch_partition_spec
-from .mesh import DATA_AXIS, data_mesh, mesh_size
+from .mesh import DATA_AXIS, batch_partition_spec, data_mesh, mesh_size
 
 # (config, backend, mesh) -> jitted sharded executable. A plain dict (not
 # lru_cache): Mesh objects are hashable, and data_mesh() returns cached
@@ -126,7 +125,7 @@ def infer_batch_sharded(params, thresholds, cfg, images, *,
     # shard_map program are banned by the audit's host-sync rule): one span
     # per call with the shard geometry, not one per device
     if spec[0] is None:
-        # the resolver's divisibility fallback fired: pad to divisible
+        # the batch does not divide the mesh: pad to divisible
         pad = (-B) % n
         with obs.span("parallel.shard_execute", backend=backend, B=B,
                       devices=n, shard_B=(B + pad) // n, padded=pad):

@@ -14,8 +14,8 @@ module builds that mesh:
   devices, which is how the sharded tests run everywhere (see
   ``docs/PARALLEL.md``).
 
-The axis is named ``"data"`` to match ``sharding/resolver.py``'s rules, so
-the resolver's divisibility fallback applies unchanged to the batch axis.
+:func:`batch_partition_spec` is the one sharding rule: the batch dimension
+goes over the ``"data"`` axis when it divides it, and stays whole otherwise.
 """
 from __future__ import annotations
 
@@ -23,7 +23,7 @@ import functools
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import Mesh, PartitionSpec
 
 DATA_AXIS = "data"
 
@@ -60,3 +60,11 @@ def data_mesh(n_devices: int | None = None) -> Mesh:
 def mesh_size(mesh: Mesh | None) -> int:
     """Total devices in ``mesh`` (1 for ``None`` — the no-mesh fallback)."""
     return 1 if mesh is None else int(np.prod(list(mesh.shape.values())))
+
+
+def batch_partition_spec(mesh: Mesh, shape) -> PartitionSpec:
+    """Spec for a batch-leading array: dimension 0 over ``DATA_AXIS`` when
+    the batch divides that axis, every dimension unsharded otherwise (the
+    caller pads to divisible; ``parallel.infer_batch_sharded`` does)."""
+    batch = DATA_AXIS if shape[0] % mesh.shape[DATA_AXIS] == 0 else None
+    return PartitionSpec(batch, *(None,) * (len(shape) - 1))

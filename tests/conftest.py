@@ -1,7 +1,6 @@
 import os
 
-# Tests must see the single real CPU device (the dry-run alone forces 512
-# host devices, inside launch/dryrun.py only — never globally).
+# Tests must see the single real CPU device.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import pytest
@@ -24,6 +23,23 @@ def make_snn_config():
                          depth=depth, **overrides)
 
     return make
+
+
+def event_geometries(specs):
+    """Distinct conv stages fed by spike events: every conv after the
+    analog-input first layer, one ConvPlan per (in_hw, in_c, out_c).
+
+    ``specs`` are ``configs.PAPER_SPECS``-style dicts. A plain function,
+    not a fixture, because test modules parametrize over it at collection.
+    """
+    from repro.core import engine
+
+    seen = {}
+    for d in specs:
+        plan = engine.compile_plan(d["spec"], d["hw"], d["c"])
+        for cp in plan.convs[1:]:
+            seen.setdefault((cp.in_hw, cp.in_c, cp.out_c), cp)
+    return [seen[k] for k in sorted(seen)]
 
 # hypothesis is a dev extra (see pyproject.toml); the suite must collect and
 # run without it — property-based tests import through tests/_prop.py, which

@@ -6,7 +6,9 @@ import jax.extend
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import event_geometries
 
+from repro import configs
 from repro.core import aeq, encoding
 from repro.kernels import ops, ref
 from repro.kernels.spike_pipeline import fused_spike_accum_pallas
@@ -68,16 +70,6 @@ def test_quant_matmul_default_is_compiled():
         assert ops.default_quant_impl() == "ref"
 
 
-@pytest.mark.parametrize("t,d,s", [(16, 8, 12), (64, 32, 50), (10, 128, 40)])
-def test_moe_gather_sweep(t, d, s):
-    rng = np.random.default_rng(t + d)
-    x = jnp.asarray(rng.normal(size=(t, d)), jnp.float32)
-    idx = jnp.asarray(rng.integers(-1, t, s), jnp.int32)
-    np.testing.assert_allclose(
-        np.asarray(ops.moe_gather(x, idx)),
-        np.asarray(ref.moe_gather_ref(x, idx)))
-
-
 def _occupancy(hw, c_in, T, seed, p_fire=0.25):
     """Random (N=T, C, K2, P) occupancy via the real raster->phase split."""
     rng = np.random.default_rng(seed)
@@ -86,13 +78,26 @@ def _occupancy(hw, c_in, T, seed, p_fire=0.25):
     return fmt, aeq.phase_occupancy(fmt, jnp.asarray(raster))
 
 
+#: every spike-fed stage (in_hw, C_in, C_out) of the served nets: the
+#: paper's three and VGG-16, eight distinct stages each
+SERVED_STAGES = [(cp.in_hw, cp.in_c, cp.out_c) for cp in event_geometries(
+    [*configs.PAPER_SPECS.values(), configs.VGG16])]
+
+
 @pytest.mark.parametrize("hw,c_in,c_out,depth", [
     (9, 1, 8, 16), (12, 3, 16, 4), (28, 4, 32, 64), (10, 2, 8, 2),
-])
+] + [(*stage, depth) for stage in SERVED_STAGES for depth in (2, 256)])
 def test_fused_spike_accum_xla_matches_ref(hw, c_in, c_out, depth):
     """The compiled XLA realization == the scatter oracle, incl. small-depth
-    drop regimes and the non-compressed word format (hw=10)."""
+    drop regimes and the non-compressed word format (hw=10), at every
+    spike-fed stage of the served nets: the realization that runs
+    ``queue_pallas`` off the chip. Depth 256 drops nothing; depth 2 is
+    below a phase segment's occupied windows from 8x8 maps up."""
     fmt, occ = _occupancy(hw, c_in, 3, seed=hw * depth)
+    if depth == 2 and hw >= 8:
+        # the drop rule is exercised: some (step, channel, phase) queue
+        # holds more occupied windows than the depth
+        assert int((np.asarray(occ) > 0).sum(-1).max()) > depth
     rng = np.random.default_rng(1)
     w = jnp.asarray(rng.normal(size=(3, 3, c_in, c_out)), jnp.float32)
     kw = dict(K=3, n_win=fmt.n_win, bits=fmt.bits_coord, depth=depth,

@@ -12,7 +12,7 @@ Multi-device cases need more than one visible device; on CPU that means
 
 which is exactly what the CI ``devices: 4`` matrix leg sets (see
 ``docs/PARALLEL.md``). Under a single device those tests skip; the
-mesh/resolver plumbing tests run everywhere.
+mesh plumbing tests run everywhere.
 """
 import jax
 import jax.numpy as jnp
@@ -22,7 +22,7 @@ from jax.sharding import Mesh
 
 from repro import parallel
 from repro.core import engine, snn_model
-from repro.sharding.resolver import batch_partition_spec
+from repro.parallel import batch_partition_spec
 
 multi_device = pytest.mark.skipif(
     len(jax.devices()) < 2,
@@ -179,7 +179,7 @@ def test_registry_set_mesh_drops_compiled_plans(net):
 
 
 # ---------------------------------------------------------------------------
-# Mesh + resolver plumbing (runs on any device count)
+# Mesh plumbing (runs on any device count)
 # ---------------------------------------------------------------------------
 
 def test_data_mesh_shape_and_caching():
@@ -195,7 +195,7 @@ def test_data_mesh_shape_and_caching():
 
 
 def test_batch_partition_spec_divisibility_fallback():
-    # the resolver rule reused by the executor: shard iff B divides the mesh
+    # the executor's rule: shard iff B divides the mesh
     devs = np.array(jax.devices() * 4)[:4]
     mesh = Mesh(devs, ("data",))
     assert batch_partition_spec(mesh, (8, 10, 10, 1))[0] == "data"

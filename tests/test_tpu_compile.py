@@ -18,6 +18,7 @@ import os
 import jax
 import jax.numpy as jnp
 import pytest
+from conftest import event_geometries
 from jax.sharding import SingleDeviceSharding
 
 from repro import configs
@@ -30,20 +31,9 @@ SERVE_BATCH, T = 64, 4
 DEPTHS = (64, 256)  # serve/bench.py serving depth, SNNConfig default
 
 
-def _event_geometries(specs):
-    """Distinct conv stages fed by spike events: every conv after the
-    analog-input first layer, one ConvPlan per (in_hw, in_c, out_c)."""
-    seen = {}
-    for d in specs:
-        plan = engine.compile_plan(d["spec"], d["hw"], d["c"])
-        for cp in plan.convs[1:]:
-            seen.setdefault((cp.in_hw, cp.in_c, cp.out_c), cp)
-    return [seen[k] for k in sorted(seen)]
-
-
-GEOMETRIES = _event_geometries(configs.PAPER_SPECS.values())
+GEOMETRIES = event_geometries(configs.PAPER_SPECS.values())
 #: VGG-16's eight: C_out up to 512 (four lane tiles), maps down to 2x2
-VGG16_GEOMETRIES = _event_geometries([configs.VGG16])
+VGG16_GEOMETRIES = event_geometries([configs.VGG16])
 
 
 def _geom_id(cp):

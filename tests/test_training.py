@@ -1,9 +1,8 @@
-"""Optimizer, schedules, grad compression, microbatching equivalence."""
+"""Optimizer, schedules, and direct SNN training."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.training.grad_compress import compress_decompress, ef_step, init_residual
 from repro.training.optimizer import (adamw_init, adamw_update,
                                       cosine_schedule, global_norm)
 
@@ -30,77 +29,6 @@ def test_grad_clip_bounds_update():
     big = {"w": jnp.asarray([1e6, -1e6, 1e6])}
     new, _ = adamw_update(params, big, state, lr=1e-3, grad_clip=1.0)
     assert float(global_norm(jax.tree.map(lambda a, b: a - b, params, new))) < 1e-2
-
-
-def test_compress_decompress_small_error():
-    rng = np.random.default_rng(0)
-    g = {"w": jnp.asarray(rng.normal(size=(64, 64)), jnp.float32)}
-    dq = compress_decompress(g)
-    err = float(jnp.abs(dq["w"] - g["w"]).max())
-    assert err <= float(jnp.abs(g["w"]).max()) / 127.0 + 1e-6
-
-
-def test_error_feedback_preserves_signal():
-    """Sum of EF-compressed grads converges to sum of true grads."""
-    rng = np.random.default_rng(1)
-    true = [
-        {"w": jnp.asarray(rng.normal(size=(16,)) * 1e-3, jnp.float32)}
-        for _ in range(50)]
-    res = init_residual(true[0])
-    acc_dq = jnp.zeros(16)
-    acc_true = jnp.zeros(16)
-    for g in true:
-        dq, res = ef_step(g, res)
-        acc_dq += dq["w"]
-        acc_true += g["w"]
-    # residual bounds the cumulative error
-    np.testing.assert_allclose(np.asarray(acc_dq + res["w"]),
-                               np.asarray(acc_true), atol=1e-5)
-
-
-def test_microbatching_matches_full_batch():
-    """microbatches=2 gives the same update as one full batch (mean grads)."""
-    import dataclasses
-
-    from repro.models import model as M
-    from repro.training import train_loop
-
-    from _smoke_archs import SMOKES
-
-    cfg1 = SMOKES["dense-tied"]
-    cfg2 = dataclasses.replace(cfg1, microbatches=2)
-    params, _ = M.init_model(jax.random.PRNGKey(0), cfg1)
-
-    rng = np.random.default_rng(0)
-    batch = {
-        "tokens": jnp.asarray(rng.integers(0, cfg1.vocab, (4, 16)), jnp.int32),
-        "labels": jnp.asarray(rng.integers(0, cfg1.vocab, (4, 16)), jnp.int32),
-    }
-    s1, m1 = train_loop.make_train_step(cfg1)(train_loop.init_state(params), batch)
-    s2, m2 = train_loop.make_train_step(cfg2)(train_loop.init_state(params), batch)
-    assert abs(float(m1["loss"]) - float(m2["loss"])) < 5e-3
-    d = jax.tree.map(lambda a, b: float(jnp.abs(a - b).max()),
-                     s1.params, s2.params)
-    assert max(jax.tree.leaves(d)) < 5e-3
-
-
-def test_data_pipeline_determinism():
-    from repro.data.pipeline import TokenStream
-
-    s1 = TokenStream(128, 16, 4, seed=5)
-    s2 = TokenStream(128, 16, 4, seed=5)
-    b1, b2 = s1.batch(7), s2.batch(7)
-    np.testing.assert_array_equal(np.asarray(b1["tokens"]),
-                                  np.asarray(b2["tokens"]))
-    # labels are next-token shifted
-    np.testing.assert_array_equal(np.asarray(b1["tokens"][:, 1:]),
-                                  np.asarray(b1["labels"][:, :-1]))
-    # host sharding slices the same global batch
-    h0 = TokenStream(128, 16, 4, seed=5, host_index=0, num_hosts=2).batch(3)
-    h1 = TokenStream(128, 16, 4, seed=5, host_index=1, num_hosts=2).batch(3)
-    full = TokenStream(128, 16, 4, seed=5).batch(3)
-    np.testing.assert_array_equal(
-        np.concatenate([h0["tokens"], h1["tokens"]]), np.asarray(full["tokens"]))
 
 
 # ---------------------------------------------------------------------------
