@@ -23,6 +23,7 @@ from __future__ import annotations
 from typing import NamedTuple
 
 import jax.numpy as jnp
+import numpy as np
 
 # --- energy constants [pJ] -------------------------------------------------
 E_FP32_ADD = 0.11       # membrane potential accumulate (SNN is add-only)
@@ -75,10 +76,19 @@ def snn_energy(
       the paper's LUTRAM move) or HBM (BRAM-like spill)
     - throughput: events_per_cycle events/cycle (the K^2 conflict-free
       phases), each driving `lanes` output-channel accumulates
+
+    The arithmetic follows its input: numpy counts are priced in numpy on
+    the host, jax counts in jax. Each per-sample sum keeps the count's own
+    dtype, so int32 counts wrap in int32 either way.
     """
-    adds = stats.add_ops.sum(-1).astype(jnp.float32)
-    events = stats.events_in.sum(-1).astype(jnp.float32)
-    spikes = stats.spikes_out.sum(-1).astype(jnp.float32)
+    xp = np if isinstance(stats.add_ops, np.ndarray) else jnp
+
+    def total(counts):
+        return counts.sum(-1, dtype=counts.dtype).astype(xp.float32)
+
+    adds = total(stats.add_ops)
+    events = total(stats.events_in)
+    spikes = total(stats.spikes_out)
 
     compute = adds * E_FP32_ADD
     queue_bytes = (events + spikes) * word_bytes
@@ -88,9 +98,9 @@ def snn_energy(
         vmem = (queue_bytes + mem_traffic) * E_VMEM_BYTE
     else:
         hbm = (queue_bytes + mem_traffic) * E_HBM_BYTE
-        vmem = jnp.zeros_like(hbm)
+        vmem = xp.zeros_like(hbm)
 
-    cycles = jnp.maximum(adds / (events_per_cycle * lanes), events)
+    cycles = xp.maximum(adds / (events_per_cycle * lanes), events)
     latency = cycles / CLOCK_HZ
     return EnergyBreakdown(compute, hbm, vmem, compute + hbm + vmem, latency)
 
@@ -104,24 +114,23 @@ def reprice(
     events_per_cycle: int = 9,
     lanes: int = 128,
 ) -> EnergyBreakdown:
-    """Price *recorded* stats — the study pipeline's repricing entry point.
+    """Price *recorded* stats on the host — the study pipeline's repricing
+    entry point.
 
-    Accepts a live :class:`~repro.core.snn_model.SNNStats`, the study
-    package's :class:`~repro.study.artifacts.StatsRecord` (anything with an
-    ``as_snn_stats()``), or a stats tuple holding plain numpy arrays, and
-    prices it identically to pricing a fresh inference: all inputs to
-    :func:`snn_energy` are integer counts, so repricing is exact. This is
-    what lets encoding / residency / bit-width sweeps run SNN inference
-    once and re-derive every energy number from the record.
+    Accepts the study package's :class:`~repro.study.artifacts.StatsRecord`,
+    a stats tuple holding plain numpy arrays, or a live
+    :class:`~repro.core.snn_model.SNNStats` (its counts are pulled to the
+    host), and prices the counts with :func:`snn_energy` in numpy: no
+    device program runs. Each per-sample sum accumulates in the count's own
+    dtype, so int32 counts keep the int32 wrap a live jax pricing has. All
+    inputs are integer counts, so repricing is exact — what lets encoding /
+    residency / bit-width sweeps run SNN inference once and re-derive every
+    energy number from the record, and what lets the serving runtime meter
+    a bucket from the one host copy it fetched.
     """
-    rehydrate = getattr(stats, "as_snn_stats", None)
-    if rehydrate is not None:
-        stats = rehydrate()
-    else:
-        stats = stats._replace(
-            **{f: jnp.asarray(getattr(stats, f))
-               for f in ("events_in", "spikes_out", "add_ops", "overflow",
-                         "queue_words")})
+    stats = stats._replace(
+        **{f: np.asarray(getattr(stats, f))
+           for f in ("events_in", "spikes_out", "add_ops")})
     return snn_energy(stats, word_bytes=word_bytes, mem_bytes=mem_bytes,
                       vmem_resident=vmem_resident,
                       events_per_cycle=events_per_cycle, lanes=lanes)

@@ -38,8 +38,9 @@ class InferResponse:
     ``energy_j`` / ``model_latency_s`` come from pricing ``stats`` (this
     request's row, sliced out of the bucket's batched SNNStats) through
     ``repro.study.price_record`` — the same arithmetic the study pipeline's
-    price stage applies to a whole eval set, so per-request totals sum
-    bit-exactly to a one-shot collect+price over the same inputs.
+    price stage applies to a whole eval set, run in numpy on the host for
+    both, so per-request totals sum bit-exactly to a one-shot
+    collect+price over the same inputs.
     """
 
     rid: int

@@ -14,9 +14,11 @@ Per-request accounting: the bucket's batched :class:`SNNStats` carries a
 leading per-sample axis, so request ``i``'s row slices out as a (1, L)
 :class:`~repro.study.artifacts.StatsRecord` and is priced through
 ``repro.study.price_record`` — the price stage's own arithmetic — into the
-response's ``energy_j`` / ``model_latency_s``. Because both the slicing and
-the pricing are per-sample exact, the energy totals of served requests sum
-bit-exactly to a one-shot ``collect`` + ``price`` over the same inputs
+response's ``energy_j`` / ``model_latency_s``. The metering phase runs no
+device program: one ``jax.device_get`` brings the logits and the stats to
+the host, and the batch is priced there in numpy. Because both the slicing
+and the pricing are per-sample exact, the energy totals of served requests
+sum bit-exactly to a one-shot ``collect`` + ``price`` over the same inputs
 (pinned by ``tests/test_serving.py`` and measured by ``benchmarks/run.py``'s
 ``serve_bench`` rows).
 """
@@ -25,6 +27,7 @@ from __future__ import annotations
 import collections
 import time
 
+import jax
 import numpy as np
 
 from .. import obs
@@ -210,19 +213,19 @@ class ServeRuntime:
                 obs.counter("serve.requests", len(taken))
 
             with obs.span("serve.fetch") as fetch:
-                logits = np.asarray(logits)
-                ev = np.asarray(stats.events_in)
-                sp = np.asarray(stats.spikes_out)
-                ao = np.asarray(stats.add_ops)
-                qw = np.asarray(stats.queue_words)
-                ovf = np.asarray(stats.overflow)
+                # audit: allow[host-sync] responses and meters are host
+                # values: ONE transfer call for the logits and the five
+                # stats arrays (device_get starts every copy, then waits)
+                logits, stats = jax.device_get((logits, stats))
+                ev, sp, ao = stats.events_in, stats.spikes_out, stats.add_ops
+                qw, ovf = stats.queue_words, stats.overflow
                 fetch.set(bytes=sum(a.nbytes
                                     for a in (logits, ev, sp, ao, qw, ovf)))
 
-            # price the whole batch in ONE price_record call (repricing is
-            # elementwise per sample, so row i of a batch pricing bit-equals
-            # pricing row i alone — and per-request jnp dispatch overhead
-            # would otherwise dominate small-model serving cost)
+            # price the whole batch in ONE price_record call, in numpy on
+            # the fetched counts: no upload, no device program, no pull
+            # (repricing is elementwise per sample, so row i of a batch
+            # pricing bit-equals pricing row i alone)
             batch_record = StatsRecord(events_in=ev, spikes_out=sp,
                                        add_ops=ao, queue_words=qw,
                                        overflow=ovf)
@@ -230,8 +233,8 @@ class ServeRuntime:
                 e = price_record(batch_record, input_hw=handle.cfg.input_hw,
                                  compressed=handle.cfg.compressed,
                                  vmem_resident=handle.vmem_resident)
-                energy_j = np.asarray(e.total_j)
-                model_latency_s = np.asarray(e.latency_s)
+                energy_j = e.total_j
+                model_latency_s = e.latency_s
 
             with obs.span("serve.respond"):
                 responses = []

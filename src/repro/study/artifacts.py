@@ -3,7 +3,8 @@
 Each stage produces exactly one artifact type; every artifact carries the
 content key it was cached under, so provenance survives across the memory
 and disk tiers. All bulk payloads are numpy (framework-free pickles); the
-stages rehydrate to jax arrays at use sites.
+stages rehydrate them to jax arrays where a device computes on them, and
+price a :class:`StatsRecord` on the host as it is.
 """
 from __future__ import annotations
 
@@ -56,20 +57,6 @@ class StatsRecord(NamedTuple):
     add_ops: np.ndarray      # (N, L) scalar accumulations
     queue_words: np.ndarray  # (N, L) peak words resident per layer queue
     overflow: np.ndarray     # (N,)  dropped events per sample
-
-    def as_snn_stats(self):
-        """Rehydrate to an engine :class:`SNNStats` of jax arrays."""
-        import jax.numpy as jnp
-
-        from ..core.snn_model import SNNStats
-
-        return SNNStats(
-            events_in=jnp.asarray(self.events_in),
-            spikes_out=jnp.asarray(self.spikes_out),
-            add_ops=jnp.asarray(self.add_ops),
-            overflow=jnp.asarray(self.overflow),
-            queue_words=jnp.asarray(self.queue_words),
-        )
 
 
 class CollectArtifact(NamedTuple):

@@ -20,11 +20,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import engine, snn_model
+from repro.core import encoding, energy, engine, snn_model
 from repro.serve import (BucketPolicy, ModelRegistry, ServeError,
                          ServeRuntime)
 from repro.study import StudyCache, StudySpec, price_record, stages
-from repro.study.artifacts import ConvertArtifact
+from repro.study.artifacts import ConvertArtifact, StatsRecord
 
 SPEC = "6C3-P2-4C3-8"
 HW, C = 10, 1
@@ -184,6 +184,61 @@ def test_per_request_pricing_matches_one_shot_collect_price(net):
     for r in responses:
         np.testing.assert_array_equal(
             r.stats.add_ops[0], collected.stats.add_ops[r.rid])
+
+
+@pytest.mark.parametrize("magnitude", [100_000, 600_000_000])
+@pytest.mark.parametrize("rows", [1, 13, 64])
+@pytest.mark.parametrize("vmem_resident", [True, False])
+def test_host_pricing_bit_equals_jax_pricing(vmem_resident, rows, magnitude):
+    """Pricing a numpy record stays on the host (numpy out, no jax.Array)
+    and bit-equals ``snn_energy`` over the same counts as jax arrays —
+    int32 wrap of the per-sample sums included."""
+    rng = np.random.default_rng(rows)
+    low = magnitude - magnitude // 10
+    counts = {f: rng.integers(low, magnitude, (rows, 4), dtype=np.int32)
+              for f in ("events_in", "spikes_out", "add_ops", "queue_words")}
+    record = StatsRecord(**counts, overflow=np.zeros(rows, np.int32))
+    host = price_record(record, input_hw=32, compressed=True,
+                        vmem_resident=vmem_resident)
+    fmt = encoding.make_format(32, 3, compressed=True)
+    live = snn_model.SNNStats(**{f: jnp.asarray(a)
+                                 for f, a in record._asdict().items()})
+    ref = energy.snn_energy(live, word_bytes=encoding.word_nbytes(fmt),
+                            vmem_resident=vmem_resident)
+    for name, got, want in zip(("compute", "hbm", "vmem", "total",
+                                "latency", "total_j"),
+                               (*host, host.total_j), (*ref, ref.total_j)):
+        assert type(got) is np.ndarray and got.dtype == np.float32, name
+        np.testing.assert_array_equal(got, np.asarray(want), err_msg=name)
+    # at 6e8 a sample's four layers sum past 2**31: the int32 sum wraps
+    # negative, exactly as the jax path's does
+    wraps = record.add_ops.sum(-1, dtype=np.int64) >= 2**31
+    assert wraps.all() == (magnitude > 2**31 // 4)
+    np.testing.assert_array_equal(host.compute_pj < 0, wraps)
+
+
+def test_served_step_runs_no_device_program_after_the_plan(net):
+    """A warmed full bucket serves without a compile: the metering phase
+    (one fetch, numpy pricing) runs no device program of its own."""
+    params, th, imgs = net
+    rt, _ = make_runtime(params, th, buckets=(4,))
+    jax.clear_caches()       # no program warm from an earlier test's step
+    rt.registry.get("toy").warmup((4,))
+    for im in imgs[:4]:
+        rt.submit(im)
+    compiles = []
+
+    def on_duration(event, duration, **kwargs):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(duration)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        responses = rt.step()
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+    assert [r.bucket for r in responses] == [4] * 4
+    assert compiles == []
 
 
 def test_sustained_stream_cannot_starve_other_model(net):
